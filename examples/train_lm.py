@@ -21,6 +21,7 @@ from repro.core import DynamicIndex, Warren
 from repro.data.pipeline import (IndexedCorpusLoader, ingest,
                                  mark_duplicates, segment)
 from repro.data.synth import doc_generator
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig, run_with_restarts
@@ -49,6 +50,7 @@ def main():
                     help="inject a failure to demo checkpoint/restart")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = PRESETS[args.preset]
     ckpt_dir = args.ckpt_dir or os.path.join(tempfile.gettempdir(),

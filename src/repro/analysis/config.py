@@ -16,10 +16,15 @@ from __future__ import annotations
 
 import os
 import re
+import tomllib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
 
-from . import toml_lite
+
+def load_toml(path: str) -> dict:
+    """One analysis config file, parsed by the stdlib."""
+    with open(path, "rb") as fh:
+        return tomllib.load(fh)
 
 
 # --------------------------------------------------------------------- #
@@ -61,7 +66,7 @@ class Hierarchy:
     def load(cls, path: Optional[str]) -> "Hierarchy":
         if path is None:
             return cls()
-        doc = toml_lite.load(path)
+        doc = load_toml(path)
         levels: Dict[str, LockLevel] = {}
         for name, spec in doc.get("locks", {}).items():
             if not isinstance(spec, dict) or "rank" not in spec:

@@ -12,7 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from . import toml_lite
+from .config import load_toml
 
 
 @dataclass
@@ -43,7 +43,7 @@ class Suppressions:
     def load(cls, path: Optional[str]) -> "Suppressions":
         if path is None:
             return cls()
-        doc = toml_lite.load(path)
+        doc = load_toml(path)
         entries: Dict[str, str] = {}
         for item in doc.get("suppress", []):
             sid = item.get("id", "")

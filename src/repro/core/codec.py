@@ -10,6 +10,7 @@ blob header gained the codec byte.
 
 from __future__ import annotations
 
+import threading
 import zlib
 
 try:
@@ -20,8 +21,17 @@ except ImportError:          # pure-stdlib fallback
 ZSTD = 1
 ZLIB = 2
 
-_zstd_c = _zstd.ZstdCompressor(level=3) if _zstd is not None else None
-_zstd_d = _zstd.ZstdDecompressor() if _zstd is not None else None
+# zstandard's (de)compressor objects must not be used by two threads at
+# once: log writers and readers run concurrently, so each thread has its own
+_local = threading.local()
+
+
+def _zstd_ctx(name: str, make):
+    ctx = getattr(_local, name, None)
+    if ctx is None:
+        ctx = make()
+        setattr(_local, name, ctx)
+    return ctx
 
 
 def have_zstd() -> bool:
@@ -31,8 +41,8 @@ def have_zstd() -> bool:
 def compress(data: bytes, level: int = 3) -> bytes:
     """Compress with the best available codec; blob[0] is the codec id."""
     if _zstd is not None:
-        cctx = (_zstd_c if level == 3
-                else _zstd.ZstdCompressor(level=level))
+        cctx = (_zstd_ctx("c", lambda: _zstd.ZstdCompressor(level=3))
+                if level == 3 else _zstd.ZstdCompressor(level=level))
         return bytes([ZSTD]) + cctx.compress(data)
     return bytes([ZLIB]) + zlib.compress(data, min(level + 3, 9))
 
@@ -49,7 +59,7 @@ def decompress(blob: bytes) -> bytes:
         if _zstd is None:
             raise RuntimeError(
                 "blob was written with zstandard, which is not installed")
-        return _zstd_d.decompress(blob[1:])
+        return _zstd_ctx("d", _zstd.ZstdDecompressor).decompress(blob[1:])
     if codec == ZLIB:
         return zlib.decompress(blob[1:])
     raise ValueError(f"unknown codec byte {codec}")

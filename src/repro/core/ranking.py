@@ -275,6 +275,17 @@ def build_block_impacts(snapshot_or_warren, terms: Sequence[str],
                             tb, stats.doc_starts.copy())
 
 
+def block_impact_array(bidx: BlockImpactIndex) -> np.ndarray:
+    """The dense block-major layout the Pallas kernel scores: impacts
+    ``[n_blocks, n_terms, block_size]`` float32, 0 where a term is absent;
+    flat document index = block * block_size + slot."""
+    bs = bidx.block_size
+    out = np.zeros((bidx.n_blocks, len(bidx.term_blocks), bs), np.float32)
+    for ti, t in enumerate(bidx.term_blocks):
+        out[t["di"] // bs, ti, t["di"] % bs] = t["imp"]
+    return out
+
+
 def score_blockmax(bidx: BlockImpactIndex, k: int = 10) -> List[Tuple[int, float]]:
     """Block-Max scoring over the block-impact layout (host reference)."""
     if not bidx.term_blocks:

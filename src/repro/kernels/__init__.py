@@ -2,7 +2,8 @@
 
 Each kernel subpackage ships kernel.py (pl.pallas_call + BlockSpec),
 ops.py (jit'd wrapper with a pure-jnp fallback), and ref.py (oracle).
-Kernels target TPU and are validated in interpret mode on CPU; model code
+``platform.py`` decides how a kernel runs: compiled when the program is
+lowered for a TPU, interpreted when it is lowered for the CPU.  Model code
 takes a `use_pallas` flag (default off so the multi-pod dry-run lowers the
 pure-jnp path).
 """

@@ -9,41 +9,51 @@ from .kernel import blockmax_scores_pallas
 from .ref import bm25_topk_ref
 
 
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret",
+def _top_k(x, k: int):
+    """``lax.top_k`` of a 1-D array, taken over a [1, N] view: for a TPU
+    the compiler spends tens of seconds on a rank-1 top_k of 2^15 or more
+    elements, and about a second on the same length as one row."""
+    values, idx = jax.lax.top_k(x.reshape(1, -1), k)
+    return values[0], idx[0]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "use_pallas",
                                              "probe_blocks"))
 def bm25_blockmax_topk(impacts, block_max, k: int, use_pallas: bool = True,
-                       interpret: bool = True, probe_blocks: int = None):
+                       probe_blocks: int = None):
     """Top-k docs by BM25 with block-max pruning.
 
-    impacts    [T, NB, BS] dense block-impact layout (0 where term absent)
-    block_max  [T, NB]     per-(term, block) maxima
+    impacts    [NB, T, BS] block-major impact layout (0 where term absent)
+    block_max  [NB, T]     per-(block, term) maxima
     Returns (scores [k], flat_doc_ids [k]); exact (pruning is conservative).
     """
-    t, nb, bs = impacts.shape
+    nb, t, bs = impacts.shape
     if not use_pallas:
         return bm25_topk_ref(impacts, k)
 
     # --- θ pre-pass: exactly score the highest-UB blocks ----------------- #
     probe = probe_blocks or max(1, min(nb, -(-k // bs) * 2))
-    ub = block_max.sum(axis=0)                       # [NB]
-    _, best_blocks = jax.lax.top_k(ub, probe)        # indices of probe blocks
-    probe_imp = jnp.take(impacts, best_blocks, axis=1)   # [T, probe, BS]
-    probe_scores = probe_imp.sum(axis=0).reshape(-1)     # [probe * BS]
-    kth = jax.lax.top_k(probe_scores, min(k, probe * bs))[0][-1]
-    theta = kth  # conservative: true kth-best is >= kth over a subset? No —
-    # kth over a SUBSET is <= true kth-best, so pruning on it is safe.
+    ub = block_max.sum(axis=1)                       # [NB]
+    _, best_blocks = _top_k(ub, probe)               # indices of probe blocks
+    probe_imp = jnp.take(impacts, best_blocks, axis=0)   # [probe, T, BS]
+    probe_scores = probe_imp.sum(axis=1).reshape(-1)     # [probe * BS]
+    # kth over a SUBSET of true scores is <= the true kth-best, so pruning
+    # on it is safe.  Lowering it by the rounding bound of a T-term float
+    # sum keeps that true when ub and the scores are summed in different
+    # orders.
+    kth = _top_k(probe_scores, min(k, probe * bs))[0][-1]
+    theta = kth * (1.0 - 2 * t * jnp.finfo(jnp.float32).eps)
 
     # --- pruned sweep ----------------------------------------------------- #
-    scores = blockmax_scores_pallas(impacts, block_max, theta,
-                                    interpret=interpret)  # [NB, BS]
+    scores = blockmax_scores_pallas(impacts, ub, theta)  # [NB, BS]
     # pruned blocks carry -inf; clamp to the true score floor (impacts are
     # non-negative) so a top-k that spills past the last positive doc reads
     # 0 exactly like the exhaustive oracle
     scores = jnp.maximum(scores, 0.0)
-    return jax.lax.top_k(scores.reshape(-1), k)
+    return _top_k(scores.reshape(-1), k)
 
 
 def pruned_fraction(block_max, theta) -> jnp.ndarray:
     """Diagnostic: fraction of blocks the kernel skips at threshold θ."""
-    ub = block_max.sum(axis=0)
+    ub = block_max.sum(axis=1)
     return jnp.mean((ub < theta).astype(jnp.float32))

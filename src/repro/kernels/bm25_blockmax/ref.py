@@ -1,12 +1,11 @@
 """Pure-jnp oracle: exhaustive BM25 scoring over the block-impact layout."""
 
 import jax
-import jax.numpy as jnp
 
 
 def bm25_score_ref(impacts):
-    """impacts [T, NB, BS] → scores [NB * BS] (sum over terms, no pruning)."""
-    return impacts.sum(axis=0).reshape(-1)
+    """impacts [NB, T, BS] → scores [NB * BS] (sum over terms, no pruning)."""
+    return impacts.sum(axis=1).reshape(-1)
 
 
 def bm25_topk_ref(impacts, k: int):

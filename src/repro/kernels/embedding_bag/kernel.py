@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 
 def _bag_kernel(idx_ref, w_ref, table_ref, o_ref, *, max_bag):
     # idx_ref [B, max_bag] (SMEM, scalar prefetch); table [V, D]; out [1, D]
@@ -27,7 +29,7 @@ def _bag_kernel(idx_ref, w_ref, table_ref, o_ref, *, max_bag):
     def body(i, acc):
         row_id = idx_ref[b, i]
         w = w_ref[b, i]
-        row = pl.load(table_ref, (pl.dslice(row_id, 1), slice(None)))  # [1, D]
+        row = table_ref[pl.ds(row_id, 1), :]                           # [1, D]
         return acc + w * row[0].astype(jnp.float32)
 
     acc = jax.lax.fori_loop(0, max_bag,  body,
@@ -35,7 +37,7 @@ def _bag_kernel(idx_ref, w_ref, table_ref, o_ref, *, max_bag):
     o_ref[0, :] = acc.astype(o_ref.dtype)
 
 
-def embedding_bag_pallas(table, indices, weights, *, interpret: bool = True):
+def embedding_bag_pallas(table, indices, weights):
     """table [V, D]; indices [B, max_bag] int32 (0-padded);
     weights [B, max_bag] f32 (0 where padded) → [B, D]."""
     bsz, max_bag = indices.shape
@@ -47,9 +49,8 @@ def embedding_bag_pallas(table, indices, weights, *, interpret: bool = True):
         in_specs=[pl.BlockSpec((v, d), lambda b, *_: (0, 0))],
         out_specs=pl.BlockSpec((1, d), lambda b, *_: (b, 0)),
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, d), table.dtype),
-        interpret=interpret,
     )(indices.astype(jnp.int32), weights.astype(jnp.float32), table)

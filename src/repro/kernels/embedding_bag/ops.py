@@ -10,9 +10,8 @@ from .kernel import embedding_bag_pallas
 from .ref import embedding_bag_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def embedding_bag_padded(table, indices, weights, use_pallas: bool = False,
-                         interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def embedding_bag_padded(table, indices, weights, use_pallas: bool = False):
     """Padded-bag embedding lookup.
 
     table [V, D]; indices [B, L] (0-padded); weights [B, L] (0 on padding).
@@ -20,8 +19,7 @@ def embedding_bag_padded(table, indices, weights, use_pallas: bool = False,
     take + weighted sum; the Pallas path fuses gather and reduce.
     """
     if use_pallas:
-        return embedding_bag_pallas(table, indices, weights,
-                                    interpret=interpret)
+        return embedding_bag_pallas(table, indices, weights)
     rows = jnp.take(table, indices, axis=0)           # [B, L, D]
     return jnp.einsum("bld,bl->bd", rows, weights.astype(table.dtype))
 

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -59,8 +61,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                        ).astype(o_ref.dtype)
 
 
-def gqa_decode_pallas(q, k, v, length, *, block_size: int = 512,
-                      interpret: bool = True):
+def gqa_decode_pallas(q, k, v, length, *, block_size: int = 512):
     """q [B, Hkv, G, D]; k/v [B, S, Hkv, D]; length [B] → [B, Hkv, G, D]."""
     b, hkv, g, d = q.shape
     s = k.shape[1]
@@ -75,7 +76,7 @@ def gqa_decode_pallas(q, k, v, length, *, block_size: int = 512,
     length2 = length.astype(jnp.int32).reshape(b, 1)
 
     kernel = functools.partial(_decode_kernel, bs=bs, scale=scale)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(b, hkv, n_blocks),
         in_specs=[
@@ -91,5 +92,4 @@ def gqa_decode_pallas(q, k, v, length, *, block_size: int = 512,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        interpret=interpret,
     )(length2, q, k, v)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_call
+
 
 def _join_kernel(a_s_ref, a_e_ref, b_s_ref, b_e_ref, o_ref, *, mode, pad):
     j = pl.program_id(1)
@@ -56,7 +58,7 @@ def _join_kernel(a_s_ref, a_e_ref, b_s_ref, b_e_ref, o_ref, *, mode, pad):
 
 def interval_join_pallas(a_s, a_e, b_s, b_e, *, mode: str = "contained_in",
                          tile_a: int = 256, tile_b: int = 256,
-                         interpret: bool = True, pad: int = None):
+                         pad: int = None):
     """Returns int32 mask[NA]: 1 where A[i] is contained in (contains) some B."""
     from repro.core.vectorized import PAD
     pad = int(PAD if pad is None else pad)
@@ -71,7 +73,7 @@ def interval_join_pallas(a_s, a_e, b_s, b_e, *, mode: str = "contained_in",
     b_s2, b_e2 = padto(b_s, nb_p), padto(b_e, nb_p)
 
     grid = (na_p // tile_a, nb_p // tile_b)
-    out = pl.pallas_call(
+    out = pallas_call(
         lambda *refs: _join_kernel(*refs, mode=mode, pad=pad),
         grid=grid,
         in_specs=[
@@ -82,6 +84,5 @@ def interval_join_pallas(a_s, a_e, b_s, b_e, *, mode: str = "contained_in",
         ],
         out_specs=pl.BlockSpec((1, tile_a), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, na_p), jnp.int32),
-        interpret=interpret,
     )(a_s2, a_e2, b_s2, b_e2)
     return out[0, :na]

@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.cache import enable_compile_cache
 
 
 def serve_lm(args):
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--async-scatter", action="store_true",
                     help="with --shards: pool-based per-group fan-out")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "lm":
         serve_lm(args)
     else:

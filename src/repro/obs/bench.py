@@ -2,10 +2,10 @@
 
 ``emit()`` freezes the current registry snapshot into a ``BENCH_*.json``
 file stamped with ``schema = "repro.bench/v1"`` and a *kind* (serving /
-build / kernels / autopilot).  Committing those files turns git history into the
-repo's performance trajectory: any PR that moves p95 scatter latency or
-kernel roofline fraction shows up as a diff on a tracked file rather
-than a silent regression.
+build / autopilot).  Committing those files turns git history into the
+repo's CPU smoke trajectory: any PR that moves p95 scatter latency shows
+up as a diff on a tracked file.  They are CPU runs, not device
+measurements.
 
 ``validate()`` checks a file against the schema — kind-specific required
 metrics included — and returns a list of problems (empty = valid).  The
@@ -23,14 +23,13 @@ from typing import Dict, List, Optional, Tuple
 from .registry import MetricsRegistry, registry, sanitize
 
 SCHEMA = "repro.bench/v1"
-KINDS = ("serving", "build", "kernels", "autopilot")
+KINDS = ("serving", "build", "autopilot")
 
 # Per-kind required metric families; histograms must carry percentiles.
 REQUIRED: Dict[str, Tuple[str, ...]] = {
     "serving": ("serve_scatter_latency_ms", "serve_score_latency_ms",
                 "serve_merge_latency_ms"),
     "build": ("build_docs_per_s",),
-    "kernels": ("kernel_achieved_gflops", "kernel_phase_ms"),
     "autopilot": ("autopilot_actions_total", "autopilot_tick_ms",
                   "slo_burn_rate"),
 }

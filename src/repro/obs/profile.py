@@ -24,8 +24,8 @@ Three instruments, all cheap enough to leave on in production:
 * :func:`phase_timer` — a context manager attributing device-kernel wall
   time to phases (host ``gather``/pack vs device ``compute``), feeding
   the ``kernel_phase_ms{kernel,phase}`` family that
-  ``benchmarks/roofline.py --kernels`` reports and ``BENCH_kernels.json``
-  persists — the DMA-vs-compute baseline the Pallas speed pass needs.
+  ``benchmarks/roofline.py --kernels`` reports — the DMA-vs-compute
+  baseline the Pallas speed pass needs.
 """
 
 from __future__ import annotations

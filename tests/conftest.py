@@ -2,12 +2,6 @@ import os
 
 import pytest
 
-# Environments without the real hypothesis still run the property tests,
-# as seeded random sampling (no shrinking) — see repro/_compat.
-from repro._compat import hypothesis_stub
-
-hypothesis_stub.install()
-
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running integration test")

@@ -19,7 +19,7 @@ import pytest
 from repro import obs
 from repro.analysis import (Catalog, Hierarchy, Suppressions,
                             SuppressionError, run_analysis)
-from repro.analysis import toml_lite
+from repro.analysis.config import load_toml
 from repro.analysis.callgraph import CallGraph
 from repro.analysis.contracts import analyze_contracts
 from repro.analysis.driver import main
@@ -34,7 +34,7 @@ def ids(report):
 
 
 # --------------------------------------------------------------------- #
-# toml_lite + config plumbing
+# TOML loading + config plumbing
 # --------------------------------------------------------------------- #
 def test_toml_lite_roundtrip(tmp_path):
     p = tmp_path / "t.toml"
@@ -42,7 +42,7 @@ def test_toml_lite_roundtrip(tmp_path):
         '# comment\n[a]\nx = 1\ny = "two"\nz = [1, 2, 3]\n'
         'flag = true\n[locks."Dotted.name"]\nrank = 7\n'
         '[[suppress]]\nid = "k"\nreason = "because"\n')
-    doc = toml_lite.load(str(p))
+    doc = load_toml(str(p))
     assert doc["a"] == {"x": 1, "y": "two", "z": [1, 2, 3], "flag": True}
     assert doc["locks"]["Dotted.name"]["rank"] == 7
     assert doc["suppress"] == [{"id": "k", "reason": "because"}]

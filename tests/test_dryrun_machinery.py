@@ -69,3 +69,17 @@ def test_roofline_correction_math():
     assert out["cost"]["flops"] == 100.0 + 23 * 4.0
     assert out["cost"]["bytes accessed"] == 50.0 + 23 * 2.0
     assert out["collectives"]["all-reduce"]["bytes"] == 10.0 + 23 * 1.0
+
+
+@pytest.mark.parametrize("kind,ok", [("TPU v5 lite", True), ("cpu", False),
+                                     ("TPU v4", False)])
+def test_roofline_peaks_refuse_unknown_device(kind, ok):
+    """Peaks come from the published table only: a device it does not
+    name is an error, never a default."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks.roofline import peaks
+    if ok:
+        assert peaks(kind)["hbm_bw"] == 819e9
+    else:
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks(kind)

@@ -1,4 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps.
+
+On CPU arrays every kernel runs through the Pallas interpreter
+(``repro.kernels.platform``); ``test_tpu_compile.py`` compiles the served
+ones for the chip."""
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +59,9 @@ def test_interval_join_matches_lazy_engine():
                                        (2, 4, 256, 5), (16, 16, 128, 100)])
 def test_bm25_blockmax_sweep(t, nb, bs, k):
     rng = np.random.default_rng(t * 100 + nb)
-    # sparse impacts: ~10% fill
-    impacts = rng.random((t, nb, bs), dtype=np.float32)
-    impacts *= rng.random((t, nb, bs)) < 0.1
+    # sparse block-major impacts: ~10% fill
+    impacts = rng.random((nb, t, bs), dtype=np.float32)
+    impacts *= rng.random((nb, t, bs)) < 0.1
     block_max = impacts.max(axis=2)
     got_s, got_i = bm25_blockmax_topk(jnp.asarray(impacts),
                                       jnp.asarray(block_max), k=k)
@@ -73,10 +77,10 @@ def test_bm25_blockmax_prunes():
     from repro.kernels import pruned_fraction
     rng = np.random.default_rng(0)
     t, nb, bs = 4, 64, 128
-    impacts = rng.random((t, nb, bs), dtype=np.float32)
-    impacts *= rng.random((t, nb, bs)) < 0.05
+    impacts = rng.random((nb, t, bs), dtype=np.float32)
+    impacts *= rng.random((nb, t, bs)) < 0.05
     # a few hot blocks
-    impacts[:, :2, :] *= 10
+    impacts[:2] *= 10
     block_max = impacts.max(axis=2)
     s, _ = bm25_blockmax_topk(jnp.asarray(impacts), jnp.asarray(block_max), k=5)
     theta = float(s[-1])
@@ -115,18 +119,19 @@ def test_bm25_blockmax_single_element_block():
 def test_bm25_blockmax_theta_tie_boundary():
     """Several blocks tied at exactly ub == θ: every tied block must be
     scored so the returned score multiset matches the oracle."""
-    imp = np.zeros((1, 4, 8), np.float32)
-    imp[0, :, 3] = 1.0                   # one doc of score 1.0 per block
+    imp = np.zeros((4, 1, 8), np.float32)
+    imp[:, 0, 3] = 1.0                   # one doc of score 1.0 per block
     _bm25_parity(imp, k=4)
 
 
 @pytest.mark.parametrize("t,nb,bs,k", [(1, 1, 100, 3), (3, 5, 100, 7),
                                        (2, 3, 7, 4)])
 def test_bm25_blockmax_block_length_not_tile_divisible(t, nb, bs, k):
-    """BS not a multiple of the 128-lane tile (interpret-mode contract)."""
+    """BS not a multiple of the 128-lane tile: a (T, BS) block that spans
+    the whole array's last two dims is still a legal TPU tile."""
     rng = np.random.default_rng(t * 31 + nb)
-    imp = rng.random((t, nb, bs), dtype=np.float32)
-    imp *= rng.random((t, nb, bs)) < 0.2
+    imp = rng.random((nb, t, bs), dtype=np.float32)
+    imp *= rng.random((nb, t, bs)) < 0.2
     _bm25_parity(imp.astype(np.float32), k=min(k, nb * bs))
 
 
@@ -134,8 +139,8 @@ def test_bm25_blockmax_k_exceeds_positive_docs():
     """Top-k spilling past the last positive doc pads with zeros, like the
     exhaustive oracle — never -inf."""
     imp = np.zeros((2, 2, 8), np.float32)
-    imp[0, 0, 1] = 3.0
-    imp[1, 1, 4] = 1.5
+    imp[0, 0, 1] = 3.0                   # block 0, term 0
+    imp[1, 1, 4] = 1.5                   # block 1, term 1
     impacts = jnp.asarray(imp)
     got_s, _ = bm25_blockmax_topk(impacts, impacts.max(axis=2), k=10)
     want_s, _ = bm25_topk_ref(impacts, 10)

@@ -5,8 +5,7 @@ erase / commit / abort sequences into a ``ShardedWarren(n_shards=3,
 replicas=2)`` and a single-index ``Warren`` and requires identical logical
 state: for every feature touched, the same annotation multiset (values +
 the text each interval annotates — addresses differ by design, stripes vs.
-sequential), and the same ``search()`` top-10.  Runs under real hypothesis
-when installed, else the seeded ``repro._compat`` sampler.
+sequential), and the same ``search()`` top-10.
 """
 
 import numpy as np

@@ -62,8 +62,6 @@ def test_static_roundtrip_forced_zlib_fallback(tmp_path, monkeypatch):
     from repro.core import codec
 
     monkeypatch.setattr(codec, "_zstd", None)
-    monkeypatch.setattr(codec, "_zstd_c", None)
-    monkeypatch.setattr(codec, "_zstd_d", None)
 
     idx = DynamicIndex()
     w = Warren(idx)
@@ -203,7 +201,6 @@ def test_codec_legacy_raw_zstd_frame_without_zstd(monkeypatch):
     from repro.core import codec
 
     monkeypatch.setattr(codec, "_zstd", None)
-    monkeypatch.setattr(codec, "_zstd_d", None)
     legacy = b"\x28\xb5\x2f\xfd" + b"\x00" * 16   # zstd magic + frame bytes
     with np.testing.assert_raises(RuntimeError):
         codec.decompress(legacy)
@@ -215,6 +212,34 @@ def test_codec_legacy_raw_zstd_frame_without_zstd(monkeypatch):
     blob = codec.compress(b"fallback payload" * 10)
     assert blob[0] == codec.ZLIB
     assert codec.decompress(blob) == b"fallback payload" * 10
+
+
+def test_codec_roundtrips_from_many_threads():
+    """Log writers and readers compress and decompress at once: every
+    thread's blobs must round-trip (one shared zstd context would not)."""
+    import threading
+
+    from repro.core import codec
+
+    errors = []
+
+    def worker(tid):
+        rng = np.random.default_rng(tid)
+        try:
+            for i in range(200):
+                data = rng.integers(0, 16, size=2000 + i,
+                                    dtype=np.uint8).tobytes()
+                if codec.decompress(codec.compress(data)) != data:
+                    errors.append((tid, i))
+        except Exception as e:          # noqa: BLE001 - reported below
+            errors.append((tid, repr(e)))
+
+    threads = [threading.Thread(target=worker, args=(t,)) for t in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert errors == []
 
 
 def test_graph_store_friends():

@@ -7,8 +7,7 @@ erase / commit / abort sequences into a ``TieredWarren`` (with forced
 mid-sequence freezes and run compactions) and a plain single-index
 ``Warren``; because both sides allocate addresses from one sequential hot
 index, every feature's annotation list, every ``translate``, and the BM25
-top-10 must be *bit-identical*.  Runs under real hypothesis when
-installed, else the seeded ``repro._compat`` sampler.
+top-10 must be *bit-identical*.
 """
 
 import os

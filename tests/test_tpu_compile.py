@@ -1,0 +1,66 @@
+"""Compile the served scorers for a TPU v5e without the chip.
+
+The TPU compiler is installed with jaxlib and compiles for a described,
+unattached chip.  What it refuses here (tiling, VMEM, memory) it would
+refuse on the chip, so these tests guard the device path on a CPU host.
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, and every test worker imports
+every test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.vectorized import bm25_topk
+from repro.kernels import bm25_blockmax_topk
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("n_docs", [1 << 16, 1 << 23])
+def test_bm25_topk_compiles_for_v5e(one_chip, n_docs):
+    """The served scorer at a micro-batch's shape: 16 queries × 8 terms ×
+    4096 postings, into a group accumulator of ``n_docs`` slots."""
+    q, t, l = 16, 8, 4096
+    fn = jax.jit(lambda d, i, m: bm25_topk(d, i, m, n_docs=n_docs, k=10))
+    compiled = fn.lower(_spec((q, t, l), jnp.int32, one_chip),
+                        _spec((q, t, l), jnp.float32, one_chip),
+                        _spec((q, t), jnp.float32, one_chip)).compile()
+    # about two [Q, n_docs] f32 arrays (accumulator and top-k working copy):
+    # 1 GiB at 2^23, which leaves most of the chip's 16 GB of HBM free
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * q * n_docs * 4
+
+
+def test_bm25_blockmax_compiles_for_v5e(one_chip):
+    """The block-max kernel at a real width: 8 terms over 2^15 blocks of
+    128 documents (4M documents) compiles through Mosaic, not the
+    interpreter."""
+    nb, t, bs = 1 << 15, 8, 128
+    fn = jax.jit(lambda i, m: bm25_blockmax_topk(i, m, k=10))
+    compiled = fn.lower(_spec((nb, t, bs), jnp.float32, one_chip),
+                        _spec((nb, t), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
