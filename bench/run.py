@@ -1,0 +1,56 @@
+"""Run one benchmark cell on the chip and print its result line.
+
+    python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Set-up restores (or, the first time in a checkout, builds) the cell's
+deployment, starts ``RetrievalServer``, compiles every device shape the
+cell's traffic can reach, and replays the mix for a few seconds.  The
+window then drives the mix for ``--seconds``.  Afterwards every answer is
+checked against the plain reference (``reference.py``).  The last line of
+stdout is the result: ``correct``, ``attempted``, ``failed``, ``metrics``,
+``device`` (and ``breakdown`` with ``--trace 1``), then ``checks``, each
+compared number beside its limit.  Without a TPU it exits non-zero and
+prints no result.
+"""
+
+import time
+
+T_START = time.time()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+BENCH = Path(__file__).resolve().parent
+sys.path.insert(0, str(BENCH))
+# libtpu logs to /tmp unless told otherwise; a run writes only in its checkout
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, str(BENCH.parent / "src"))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+
+    import harness
+    try:
+        result = harness.run(args.workload, args.seed, args.seconds,
+                             bool(args.trace), t_start=T_START)
+    except harness.device.NoAccelerator as e:
+        print(f"error: {e}", file=sys.stderr, flush=True)
+        return 2
+    for name, c in result["checks"].items():
+        print(f"check {name}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr, flush=True)
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
