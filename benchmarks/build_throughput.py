@@ -87,13 +87,6 @@ def _gauge_build(n_docs, single_s, multi_s, static_s=None) -> None:
                   ).set(static_s)
 
 
-def _emit_build_bench(path: str, extra: dict) -> None:
-    from repro.obs import bench as obs_bench
-
-    doc = obs_bench.emit(path, "build", extra={"bench": extra})
-    print(f"  wrote {path} ({doc['schema']}, kind=build)")
-
-
 def run_tiered(n_docs: int = 1500, batch: int = 64,
                freeze_segments: int = 4, max_runs: int = 3,
                smoke: bool = False):
@@ -285,18 +278,10 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="fail loudly on lost docs, an idle compactor, or "
                          "a broken mmap-serving invariant (CI guard)")
-    ap.add_argument("--emit-bench", metavar="PATH", default=None,
-                    help="write a schema-versioned BENCH_build.json from "
-                         "the obs registry snapshot (repro.obs.bench)")
     args = ap.parse_args()
     if args.tiered:
-        res = run_tiered(args.docs, smoke=args.smoke)
+        run_tiered(args.docs, smoke=args.smoke)
     elif args.mmap:
-        res = run_mmap(args.docs, smoke=args.smoke)
+        run_mmap(args.docs, smoke=args.smoke)
     else:
-        res = run(args.docs, args.writers)
-    if args.emit_bench:
-        _emit_build_bench(args.emit_bench,
-                          extra={"docs": args.docs, "tiered": args.tiered,
-                                 "mmap": args.mmap, "smoke": args.smoke,
-                                 **res})
+        run(args.docs, args.writers)

@@ -217,48 +217,9 @@ def rebalance_bench(shards: int = 3, replicas: int = 2,
         raise SystemExit("rebalanced warren diverged from the oracle")
 
 
-def _emit_serving_bench(path: str, warren, queries, extra: dict) -> None:
-    """Write a schema-versioned BENCH_serving.json from the obs registry.
-
-    When nothing above ran a RetrievalServer (single-shard runs score via
-    ``score_bm25``), a short native serving pass feeds the three
-    ``serve_*_latency_ms`` histograms first, so the emitted file always
-    carries scatter/score/merge percentiles.  Also prints the measured
-    per-op cost of a disabled-registry observation — the "instrumentation
-    left compiled in" overhead figure."""
-    import timeit
-
-    from repro import obs
-    from repro.obs import bench as obs_bench
-    from repro.train.serve import BatcherConfig, RetrievalServer
-
-    reg = obs.registry()
-    h = reg.histogram("serve_scatter_latency_ms", site="server")
-    if h.count == 0:
-        server = RetrievalServer(
-            warren, k=10, batcher=BatcherConfig(max_batch=8, max_wait_ms=2))
-        for q in queries * 3:
-            server.query(q)
-        server.close()
-
-    n = 200_000
-    t_on = timeit.timeit(lambda: h.observe(1.0), number=n) / n
-    reg.disable()
-    try:
-        t_off = timeit.timeit(lambda: h.observe(1.0), number=n) / n
-    finally:
-        reg.enable()
-    print(f"  metric overhead/op: enabled {1e9 * t_on:.0f} ns, "
-          f"disabled {1e9 * t_off:.0f} ns")
-
-    doc = obs_bench.emit(path, "serving", extra={"bench": extra})
-    print(f"  wrote {path} ({doc['schema']}, kind=serving)")
-
-
 def run(n_years: int = 3, files_per_year: int = 6, docs_per_file: int = 20,
         n_queries: int = 12, n_writers: int = 4, shards: int = 1,
-        replicas: int = 1, async_scatter: bool = False, smoke: bool = False,
-        emit_bench: str = None):
+        replicas: int = 1, async_scatter: bool = False, smoke: bool = False):
     if smoke:
         n_years, files_per_year, docs_per_file = 2, 2, 10
         n_queries, n_writers = 4, 2
@@ -401,12 +362,6 @@ def run(n_years: int = 3, files_per_year: int = 6, docs_per_file: int = 20,
             warren, [q["text"] for q in queries.values()],
             rounds=2 if smoke else 25,
             extra_docs=200 if smoke else 8000, smoke=smoke)
-    if emit_bench:
-        _emit_serving_bench(
-            emit_bench, warren, [q["text"] for q in queries.values()],
-            extra={"smoke": smoke, "shards": shards, "replicas": replicas,
-                   "async_scatter": async_scatter, "wall_s": wall,
-                   "query_executions": len(ap_log)})
     if shards > 1:
         warren.close()
     return ap_log
@@ -433,9 +388,6 @@ if __name__ == "__main__":
                          "stall (swap window), and oracle parity")
     ap.add_argument("--years", type=int, default=3)
     ap.add_argument("--writers", type=int, default=4)
-    ap.add_argument("--emit-bench", metavar="PATH", default=None,
-                    help="write a schema-versioned BENCH_serving.json from "
-                         "the obs registry snapshot (repro.obs.bench)")
     args = ap.parse_args()
     if args.rebalance_mid_run:
         rebalance_bench(shards=max(args.shards, 2), replicas=args.replicas,
@@ -443,4 +395,4 @@ if __name__ == "__main__":
     else:
         run(n_years=args.years, n_writers=args.writers, shards=args.shards,
             replicas=args.replicas, async_scatter=args.async_scatter,
-            smoke=args.smoke, emit_bench=args.emit_bench)
+            smoke=args.smoke)

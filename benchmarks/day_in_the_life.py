@@ -27,10 +27,7 @@ Three passes, one report:
    with served rankings checked bit-identical to a single-index oracle
    after every action.
 
-``--smoke`` shrinks all passes to CI size; ``--emit-bench PATH`` writes
-a schema-versioned ``BENCH_autopilot.json`` (repro.bench/v1) carrying the
-``autopilot_*`` and ``slo_burn_rate`` metric families plus the p95
-trajectories.
+``--smoke`` shrinks all passes to CI size.
 """
 
 import math
@@ -295,26 +292,17 @@ def witness_pass(smoke: bool, baseline_wall: float) -> dict:
 
 
 def run(seed: int = 11, ticks: int = 400, flatness: float = 1.5,
-        smoke: bool = False, emit_bench: str = None,
-        lock_witness: bool = False):
+        smoke: bool = False, lock_witness: bool = False):
     if smoke:
         ticks = min(ticks, 150)
-    sim = sim_day(seed, ticks, flatness)
-    burn = burn_day(seed, ticks)
+    sim_day(seed, ticks, flatness)
+    burn_day(seed, ticks)
     import tempfile
 
     with tempfile.TemporaryDirectory(prefix="ditl-static-") as d:
         real = real_warren_pass(smoke, d)
     if lock_witness:
-        real["witness"] = witness_pass(smoke, real["wall_s"])
-    if emit_bench:
-        from repro.obs import bench as obs_bench
-
-        doc = obs_bench.emit(emit_bench, "autopilot",
-                             extra={"bench": {"smoke": smoke, "sim": sim,
-                                              "burn": burn, "real": real}})
-        print(f"  wrote {emit_bench} ({doc['schema']}, kind=autopilot)")
-
+        witness_pass(smoke, real["wall_s"])
 
 if __name__ == "__main__":
     import argparse
@@ -329,9 +317,6 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run: short sim day + tiny real corpus "
                          "(same checks, same determinism)")
-    ap.add_argument("--emit-bench", metavar="PATH", default=None,
-                    help="write a schema-versioned BENCH_autopilot.json "
-                         "from the obs registry snapshot (repro.obs.bench)")
     ap.add_argument("--lock-witness", action="store_true",
                     help="re-run the real-warren pass with the runtime "
                          "LockWitness installed (analysis/lock_hierarchy"
@@ -339,5 +324,4 @@ if __name__ == "__main__":
                          "violation and reports the witness overhead")
     args = ap.parse_args()
     run(seed=args.seed, ticks=args.ticks, flatness=args.flatness,
-        smoke=args.smoke, emit_bench=args.emit_bench,
-        lock_witness=args.lock_witness)
+        smoke=args.smoke, lock_witness=args.lock_witness)

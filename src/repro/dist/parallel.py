@@ -45,40 +45,28 @@ from repro.obs import registry
 
 
 class ScatterTimings:
-    """Thread-safe accumulator for the serving-path time breakdown.
+    """Thread-safe running sums of the serving-path time breakdown.
 
     ``scatter``  fan-out reads (per-group stats + annotation lists)
-    ``score``    per-group packing + device/host scoring
+    ``score``    host impacts, per-group packing + device/host scoring
     ``merge``    the global k-way merge of per-group top-k lists
 
-    Every ``add`` also feeds the per-query breakdown into the obs
-    histograms (``serve_{scatter,score,merge}_latency_ms{site=...}``),
-    which carry the percentiles; the struct itself keeps only running
-    sums for its human-readable ``summary``.  Because one instance is
-    shared across every clone of a warren (via ``_ctx``), the sums are
-    *windowed*: ``window()`` returns the delta since the last call and
-    bumps ``epoch``, so long-lived servers report per-window rates
-    instead of lifetime averages.
+    The sums back ``snapshot`` (which callers difference over a window
+    of their own), the human-readable ``summary``, and ``window()``,
+    which returns the sums since its last call and bumps ``epoch``: one
+    instance is shared across every clone of a warren (via ``_ctx``), so
+    long-lived servers report per-window rates instead of lifetime
+    averages.  Per-stage distributions come from the obs spans around
+    the same stages (``span_ms{span}``).
     """
 
-    def __init__(self, site: str = "warren.search"):
+    def __init__(self):
         self._lock = threading.Lock()
-        self.site = site
         self.epoch = 0
         self.scatter_s = 0.0
         self.score_s = 0.0
         self.merge_s = 0.0
         self.queries = 0
-        reg = registry()
-        self._h_scatter = reg.histogram(
-            "serve_scatter_latency_ms",
-            "per-query scatter (fan-out read) time", site=site)
-        self._h_score = reg.histogram(
-            "serve_score_latency_ms",
-            "per-query pack + device/host scoring time", site=site)
-        self._h_merge = reg.histogram(
-            "serve_merge_latency_ms",
-            "per-query global k-way merge time", site=site)
 
     def reset(self) -> None:
         """Zero the window sums and bump the epoch marker."""
@@ -94,9 +82,6 @@ class ScatterTimings:
             self.score_s += score
             self.merge_s += merge
             self.queries += queries
-        self._h_scatter.observe(1e3 * scatter)
-        self._h_score.observe(1e3 * score)
-        self._h_merge.observe(1e3 * merge)
 
     def snapshot(self) -> Dict[str, float]:
         with self._lock:

@@ -991,29 +991,19 @@ class ShardedWarren:
     def _phase1(self) -> None:
         """Quorum-ready every touched group or raise QuorumError."""
         hook = self.hooks.get("on_ready")
-        t0 = time.perf_counter()
-        try:
-            for g in sorted(self._txn_open):
-                gt = self._txn_open[g]
-                ok = gt.quorum_ready(hook=hook)
-                if ok < gt.group.quorum:
-                    reg = obs.registry()
-                    if reg.enabled:
-                        reg.counter(
-                            "txn_quorum_abort_total",
-                            "cross-shard transactions aborted because a "
-                            "touched group could not ready a quorum").inc()
-                    raise QuorumError(
-                        f"shard group {g}: {ok}/{gt.group.n_replicas} "
-                        f"replicas ready, quorum is {gt.group.quorum}")
-        finally:
-            reg = obs.registry()
-            if reg.enabled:
-                reg.histogram(
-                    "txn_quorum_wait_ms",
-                    "phase-1 time to durably ready a quorum of every "
-                    "touched group",
-                ).observe(1e3 * (time.perf_counter() - t0))
+        for g in sorted(self._txn_open):
+            gt = self._txn_open[g]
+            ok = gt.quorum_ready(hook=hook)
+            if ok < gt.group.quorum:
+                reg = obs.registry()
+                if reg.enabled:
+                    reg.counter(
+                        "txn_quorum_abort_total",
+                        "cross-shard transactions aborted because a "
+                        "touched group could not ready a quorum").inc()
+                raise QuorumError(
+                    f"shard group {g}: {ok}/{gt.group.n_replicas} "
+                    f"replicas ready, quorum is {gt.group.quorum}")
 
     def _restage(self) -> None:
         """Re-stage the logical op list against the current routing table
@@ -1036,19 +1026,21 @@ class ShardedWarren:
 
     def _ready_with_restage(self) -> None:
         """Acquire locks + phase 1, transparently re-staging (bounded) when
-        a rebalance swap rewrote a touched group under the staged txn."""
-        for _ in range(4):
-            self._acquire_locks()
-            try:
-                self._phase1()
-                return
-            except RouteEpochError:
-                self._restage()          # releases the locks; retry
-            except Exception:
-                self._abort_locked()
-                raise
-        self._abort_locked()
-        raise RouteEpochError(-1)
+        a rebalance swap rewrote a touched group under the staged txn.
+        The ``txn.ready`` span times all of it, waits for locks included."""
+        with obs.span("txn.ready"):
+            for _ in range(4):
+                self._acquire_locks()
+                try:
+                    self._phase1()
+                    return
+                except RouteEpochError:
+                    self._restage()          # releases the locks; retry
+                except Exception:
+                    self._abort_locked()
+                    raise
+            self._abort_locked()
+            raise RouteEpochError(-1)
 
     def ready(self) -> None:
         """Phase 1 now; the group write locks stay held until commit()/
@@ -1066,6 +1058,10 @@ class ShardedWarren:
         ready a majority of its replicas."""
         if not self._txn_active:
             raise RuntimeError("no active transaction")
+        with obs.span("txn.commit"):
+            return self._commit()
+
+    def _commit(self):
         if not self._txn_ready:
             self._ready_with_restage()
         mid = self.hooks.get("mid_commit")
@@ -1075,22 +1071,25 @@ class ShardedWarren:
         append_remap = None
         failed: Optional[BaseException] = None
         reg = obs.registry()
-        try:
-            for g in sorted(self._txn_open):   # phase 2: publish
-                remap, err = self._txn_open[g].commit_live()
-                if remap is None:              # every replica of g failed —
-                    failed = failed or err or RuntimeError(  # ready records
-                        f"shard group {g}: no replica published")  # durable
-                else:
-                    if reg.enabled:
-                        reg.counter("shard_write_total",
-                                    "group transactions published",
-                                    group=g).inc()
-                    if g == self._append_shard:
-                        append_remap = remap
-        finally:
-            self._release_locks()
-            self._reset_txn()
+        with obs.span("txn.publish"):
+            try:
+                for g in sorted(self._txn_open):   # phase 2: publish
+                    remap, err = self._txn_open[g].commit_live()
+                    if remap is None:
+                        # every replica of g failed; its ready records
+                        # stay durable
+                        failed = failed or err or RuntimeError(
+                            f"shard group {g}: no replica published")
+                    else:
+                        if reg.enabled:
+                            reg.counter("shard_write_total",
+                                        "group transactions published",
+                                        group=g).inc()
+                        if g == self._append_shard:
+                            append_remap = remap
+            finally:
+                self._release_locks()
+                self._reset_txn()
         if failed is not None:
             raise RuntimeError(
                 "partial cross-shard commit: some groups published, the "

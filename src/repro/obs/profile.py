@@ -21,11 +21,10 @@ Three instruments, all cheap enough to leave on in production:
   ProfiledLock acquire/release is also reported to it with the lock's
   profile name and optional ``order_key``, so the runtime lock-order
   checker sees exactly the locks the contention profiles see.
-* :func:`phase_timer` — a context manager attributing device-kernel wall
-  time to phases (host ``gather``/pack vs device ``compute``), feeding
-  the ``kernel_phase_ms{kernel,phase}`` family that
-  ``benchmarks/roofline.py --kernels`` reports — the DMA-vs-compute
-  baseline the Pallas speed pass needs.
+* :func:`phase_timer` — a context manager attributing a kernel's wall
+  time on the host to phases (see its docstring), feeding the
+  ``kernel_phase_ms{kernel,phase}`` family that the serving path and
+  ``benchmarks/roofline.py --kernels`` report.
 """
 
 from __future__ import annotations
@@ -236,10 +235,13 @@ class ProfiledLock:
 @contextmanager
 def phase_timer(kernel: str, phase: str):
     """Attribute a block's wall time to one kernel phase:
-    ``kernel_phase_ms{kernel,phase}``.  Phases by convention: ``gather``
-    (host-side packing / DMA staging) and ``compute`` (device dispatch +
-    block-until-ready).  A disabled registry reduces this to two
-    ``perf_counter`` calls."""
+    ``kernel_phase_ms{kernel,phase}``.  The served ``bm25_topk`` has four,
+    in order: ``impacts`` (host: global df/idf, per-group BM25 impacts,
+    the posting cap), ``gather`` (host: packing the padded blocks),
+    ``dispatch`` (host-to-device copies and the launch) and ``compute``
+    (the blocking wait for results and their copy back).
+    ``roofline.py --kernels`` adds ``dma`` (staging) beside ``compute``.
+    A disabled registry reduces this to one attribute check."""
     reg = registry()
     if not reg.enabled:
         yield
@@ -250,7 +252,8 @@ def phase_timer(kernel: str, phase: str):
     finally:
         reg.histogram(
             "kernel_phase_ms",
-            "device-kernel wall time by phase (gather=host pack/DMA "
-            "staging, compute=dispatch+ready)",
+            "kernel wall time on the host by phase (impacts, gather: "
+            "host work; dispatch: copies to the device and launch; "
+            "compute: blocking wait and copy back; dma: staging)",
             kernel=kernel, phase=phase,
         ).observe(1e3 * (time.perf_counter() - t0))

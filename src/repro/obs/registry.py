@@ -11,8 +11,7 @@ sites call ``registry().counter("x", group=g)`` freely; the same
 Exporters:
 
 * ``JsonlSink`` appends one ``{"ts": ..., "metrics": snapshot}`` line per
-  ``write()`` — the persisted perf-trajectory form consumed by
-  ``BENCH_*.json`` emission and ``--metrics-dump``.
+  ``write()`` — the form behind ``--metrics-dump``.
 * ``to_prometheus()`` renders the text exposition format 0.0.4
   (histograms as cumulative ``_bucket{le=...}`` series plus
   ``_sum``/``_count``), for scraping or eyeballing.

@@ -11,10 +11,16 @@ therefore yields one tree::
     serve.batch
     ├── scatter{group=0}
     │   └── replica_read{group=0, replica=0}
+    │       └── scatter.stats
     ├── scatter{group=1}
     │   └── replica_read{group=1, replica=1}
+    │       └── scatter.stats
     ├── device_score
     └── merge
+
+Every span that closes while the metrics registry is enabled also
+observes its duration in one histogram family, ``span_ms{span=<name>}``,
+so each stage's time is a metric as well as a node of a trace.
 
 Completed traces (a root span plus all its descendants) land in a ring
 buffer (:meth:`Tracer.traces`); traces slower than ``slow_ms`` are also
@@ -36,6 +42,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from .registry import registry
 from .rotate import RotatingJsonl
 
 _CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
@@ -171,6 +178,10 @@ class _SpanCtx:
             # greppable in dumps and visible in /traces; the exception
             # still propagates (we never swallow it)
             span.labels.setdefault("error", exc_type.__name__)
+        reg = registry()
+        if reg.enabled:
+            reg.histogram("span_ms", "wall time of each closed span, by name",
+                          span=span.name).observe(1e3 * span.duration_s)
         _CURRENT.reset(self._token)
         if span.parent_id is None:           # root closed: trace complete
             self._tracer._finish(span._trace)

@@ -89,7 +89,7 @@ class MicroBatcher:
             if self._stop.is_set():
                 done._put(_BatchFailure(RuntimeError("MicroBatcher closed")))
                 return done
-            self._q.put((request, done))
+            self._q.put((request, done, time.monotonic()))
         return done
 
     def _loop(self):
@@ -110,6 +110,12 @@ class MicroBatcher:
                     break
             reg = obs.registry()
             if reg.enabled:
+                launch = time.monotonic()
+                wait = reg.histogram(
+                    "serve_queue_wait_ms",
+                    "each request's wait from submit to its batch's launch")
+                for _, _, t_submit in batch:
+                    wait.observe(1e3 * (launch - t_submit))
                 reg.gauge("serve_queue_depth",
                           "requests still queued when a batch launches"
                           ).set(self._q.qsize())
@@ -119,17 +125,17 @@ class MicroBatcher:
                               ).observe(len(batch))
             try:
                 with obs.span("serve.batch", size=len(batch)):
-                    results = self.handler([r for r, _ in batch])
+                    results = self.handler([r for r, _, _ in batch])
                 if len(results) != len(batch):
                     raise RuntimeError(
                         f"handler returned {len(results)} results for a "
                         f"batch of {len(batch)}")
             except Exception as e:
                 failure = _BatchFailure(e)
-                for _, done in batch:
+                for _, done, _ in batch:
                     done._put(failure)
                 continue
-            for (_, done), res in zip(batch, results):
+            for (_, done, _), res in zip(batch, results):
                 done._put(res)
 
     def close(self):
@@ -141,7 +147,7 @@ class MicroBatcher:
         failure = _BatchFailure(RuntimeError("MicroBatcher closed"))
         while True:
             try:
-                _, done = self._q.get_nowait()
+                _, done, _ = self._q.get_nowait()
             except queue.Empty:
                 break
             done._put(failure)
@@ -170,7 +176,7 @@ class RetrievalServer:
         self.max_terms = max_terms
         self.max_postings = max_postings
         self._sharded = sharded_native and hasattr(warren, "map_groups")
-        self.timings = ScatterTimings(site="server")
+        self.timings = ScatterTimings()
         # device shapes already scored: a new (qp, tp, l, nb) tuple means
         # the jitted scorer compiles again — the counter Autopilot watches
         # to tell shape-bucket churn from steady-state serving
@@ -320,32 +326,11 @@ class RetrievalServer:
         return out
 
     # -- native ShardedWarren path ----------------------------------------- #
-    def _handle_sharded(self, queries: List[str]
-                        ) -> List[List[Tuple[int, float]]]:
-        qn, l, k = len(queries), self.max_postings, self.k
-        qterms = self._query_terms(queries)
-        # stem every query term once; pack_group indexes these features
-        qfeatures = [[ranking.TF_PREFIX + ranking.porter_stem(term)
-                      for term in terms] for terms in qterms]
-        stems = list(dict.fromkeys(f for row in qfeatures for f in row))
-        # scatter: ONE fan-out per group for the whole micro-batch — every
-        # group returns its stats and its slice of every term list (the
-        # fan-out follows the session's pinned routing table, so the group
-        # count comes from the gather, not from the live warren)
-        t0 = time.perf_counter()
-        with self.warren:
-            gathered = self.warren.map_groups(
-                lambda w: (ranking.collection_stats(w),
-                           [w.annotations(f) for f in stems]))
-        t_scatter = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        n_groups = len(gathered)
-        per = [s for s, _ in gathered]
-        lists = [lst for _, lst in gathered]
-        n_docs = sum(s.n_docs for s in per)
-        if n_docs == 0:
-            self.timings.add(scatter=t_scatter, queries=qn)
-            return [[] for _ in queries]
+    def _global_impacts(self, stems: List[str], per: list, lists: list,
+                        n_docs: int) -> dict:
+        """Per stem, every group's (doc_idx, impact) arrays under GLOBAL
+        df/idf and avgdl, or None when no group holds the stem."""
+        l, n_groups = self.max_postings, len(per)
         # global stats, computed exactly as collection_stats would over the
         # merged surface (avgdl is order-free; ties merge by address below)
         avgdl = float(np.concatenate([s.doc_lens for s in per]).mean())
@@ -381,6 +366,40 @@ class RetrievalServer:
                     capped.append((di[m], imp[m]))
                 per_g = capped
             term_group[f] = per_g
+        return term_group
+
+    def _handle_sharded(self, queries: List[str]
+                        ) -> List[List[Tuple[int, float]]]:
+        qn, k = len(queries), self.k
+        qterms = self._query_terms(queries)
+        # stem every query term once; pack_group indexes these features
+        qfeatures = [[ranking.TF_PREFIX + ranking.porter_stem(term)
+                      for term in terms] for terms in qterms]
+        stems = list(dict.fromkeys(f for row in qfeatures for f in row))
+        # scatter: ONE fan-out per group for the whole micro-batch — every
+        # group returns its stats and its slice of every term list (the
+        # fan-out follows the session's pinned routing table, so the group
+        # count comes from the gather, not from the live warren)
+        def read_group(w):
+            with obs.span("scatter.stats"):
+                stats = ranking.collection_stats(w)
+            return stats, [w.annotations(f) for f in stems]
+
+        t0 = time.perf_counter()
+        with self.warren:
+            gathered = self.warren.map_groups(read_group)
+        t_scatter = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        n_groups = len(gathered)
+        per = [s for s, _ in gathered]
+        lists = [lst for _, lst in gathered]
+        n_docs = sum(s.n_docs for s in per)
+        if n_docs == 0:
+            self.timings.add(scatter=t_scatter, queries=qn)
+            return [[] for _ in queries]
+        with obs.phase_timer("bm25_topk", "impacts"):
+            term_group = self._global_impacts(stems, per, lists, n_docs)
+
         def pack_group(g: int):
             """This group's (doc_idx, impacts, qmask) block, or None when
             the group has no documents or no postings for the batch."""
@@ -414,7 +433,7 @@ class RetrievalServer:
         # device top-k computes while group g+1's block is being packed;
         # the np.asarray collection below blocks on all of them at once
         with obs.span("device_score"):
-            pending = []
+            pending, h2d_bytes = [], 0
             for g in range(n_groups):
                 with obs.phase_timer("bm25_topk", "gather"):
                     blk = pack_group(g)
@@ -422,9 +441,17 @@ class RetrievalServer:
                     pending.append(None)
                     continue
                 doc_idx, impacts, qmask, nb = blk
-                pending.append(bm25_topk(
-                    jnp.asarray(doc_idx), jnp.asarray(impacts),
-                    jnp.asarray(qmask), n_docs=nb, k=k))
+                h2d_bytes += doc_idx.nbytes + impacts.nbytes + qmask.nbytes
+                with obs.phase_timer("bm25_topk", "dispatch"):
+                    pending.append(bm25_topk(
+                        jnp.asarray(doc_idx), jnp.asarray(impacts),
+                        jnp.asarray(qmask), n_docs=nb, k=k))
+            reg = obs.registry()
+            if reg.enabled:
+                reg.histogram("serve_h2d_bytes",
+                              "bytes copied to the device per micro-batch "
+                              "(every group's packed blocks)",
+                              lo=1.0, hi=1e10).observe(h2d_bytes)
             with obs.phase_timer("bm25_topk", "compute"):
                 group_res = [None if p is None
                              else (np.asarray(p[0]), np.asarray(p[1]))

@@ -1,20 +1,22 @@
-"""repro.obs: metric primitives, registry, tracing, bench emission.
+"""repro.obs: metric primitives, registry, tracing.
 
 Covers the concurrency contract (16-thread hammers with exact totals),
-trace-context propagation across the ScatterGather pool, the span-tree
-acceptance path through the native sharded server, disabled-mode no-ops,
-ScatterTimings windowing, and the BENCH_* schema round-trip."""
+trace-context propagation across the ScatterGather pool, the span trees
+of a search through the native sharded server and of a quorum commit,
+the per-stage metrics they feed (``span_ms``, the batcher's queue wait,
+host-to-device bytes), disabled-mode no-ops, and ScatterTimings
+windowing."""
 
 import json
 import math
 import threading
+from collections import Counter as Tally
 
 import pytest
 
 from repro import obs
 from repro.obs import (Counter, Gauge, Histogram, JsonlSink, MetricsRegistry,
                        Tracer, sanitize)
-from repro.obs import bench as obs_bench
 
 
 @pytest.fixture(autouse=True)
@@ -237,7 +239,7 @@ def test_slow_trace_dump(tmp_path):
 
 def test_scatter_timings_window_and_epoch():
     from repro.dist.parallel import ScatterTimings
-    st = ScatterTimings(site="test")
+    st = ScatterTimings()
     st.add(scatter=0.010, score=0.020, merge=0.001)
     st.add(scatter=0.030, score=0.040, merge=0.002, queries=2)
     w = st.window()
@@ -249,47 +251,8 @@ def test_scatter_timings_window_and_epoch():
     s = st.snapshot()
     assert s["epoch"] == 1
     assert s["queries"] == 1 and s["scatter_s"] == pytest.approx(0.005)
-    # ...but the obs histograms keep the full trajectory
-    h = obs.registry().histogram("serve_scatter_latency_ms", site="test")
-    assert h.count == 3
-
-
-# --------------------------------------------------------------------- #
-# bench schema                                                          #
-# --------------------------------------------------------------------- #
-
-def test_bench_emit_validate_roundtrip(tmp_path):
-    reg = MetricsRegistry()
-    st_like = reg.histogram("serve_scatter_latency_ms", site="unit")
-    for v in (1.0, 2.0, 3.0):
-        st_like.observe(v)
-    reg.histogram("serve_score_latency_ms", site="unit").observe(5.0)
-    reg.histogram("serve_merge_latency_ms", site="unit").observe(0.5)
-    p = tmp_path / "BENCH_serving.json"
-    doc = obs_bench.emit(str(p), "serving",
-                         extra={"bench": {"smoke": True}}, reg=reg)
-    assert doc["schema"] == obs_bench.SCHEMA
-    assert obs_bench.validate(str(p)) == []
-    s = doc["metrics"]["serve_scatter_latency_ms"]["series"][0]
-    assert s["count"] == 3 and {"p50", "p95", "p99"} <= set(s)
-    assert obs_bench.main(["validate", str(p)]) == 0
-
-
-def test_bench_refuses_invalid(tmp_path):
-    # no serving histograms at all -> must refuse, must not write
-    p = tmp_path / "BENCH_serving.json"
-    with pytest.raises(ValueError, match="refusing"):
-        obs_bench.emit(str(p), "serving", reg=MetricsRegistry())
-    assert not p.exists()
-    with pytest.raises(ValueError):
-        obs_bench.emit(str(p), "nonsense-kind")
-    # hand-broken doc fails validation
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"schema": "other/v9", "kind": "serving",
-                               "created": 0, "metrics": {}}))
-    problems = obs_bench.validate(str(bad))
-    assert problems
-    assert obs_bench.main(["validate", str(bad)]) == 1
+    # ...and the summary reads the current window alone
+    assert st.summary().startswith("1 queries — scatter 5.00 ")
 
 
 # --------------------------------------------------------------------- #
@@ -350,15 +313,14 @@ def test_sharded_span_tree_and_metrics(tmp_path):
 
     # metric families the sweep must have fed
     snap = reg.snapshot()
-    for fam in ("serve_scatter_latency_ms", "serve_score_latency_ms",
-                "serve_merge_latency_ms", "scatter_latency_ms",
+    for fam in ("span_ms", "serve_queue_wait_ms", "serve_h2d_bytes",
+                "kernel_phase_ms", "scatter_latency_ms",
                 "shard_read_total", "shard_write_total",
-                "txn_quorum_wait_ms", "serve_batch_size",
-                "serve_jit_recompile_total"):
+                "serve_batch_size", "serve_jit_recompile_total"):
         assert fam in snap, f"missing family {fam}"
         assert snap[fam]["series"], f"empty family {fam}"
-    server_h = reg.histogram("serve_scatter_latency_ms", site="server")
-    assert server_h.count >= 1
+    assert reg.histogram("span_ms", span="serve.batch").count >= 1
+    assert reg.histogram("span_ms", span="txn.ready").count >= 1
 
 
 def test_obs_disable_silences_instrumentation(tmp_path):
@@ -371,6 +333,217 @@ def test_obs_disable_silences_instrumentation(tmp_path):
         w.commit()
     assert obs.registry().histogram("txn_commit_latency_ms").count == before
     assert obs.tracer().span("x") is obs.tracer().span("y")
+
+
+# --------------------------------------------------------------------- #
+# per-stage spans and metrics: span_ms, queue wait, h2d bytes, commits  #
+# --------------------------------------------------------------------- #
+
+STAGE_DOCS = 24
+
+
+def _sharded_warren(tmp_path, replicas=1):
+    """Three groups, every one holding documents: one transaction per
+    document, so the append routing spreads them."""
+    from repro.core import index_document
+    from repro.dist.shard_router import ShardedWarren
+    warren = ShardedWarren(n_shards=3, replicas=replicas,
+                           static_dir=str(tmp_path), async_scatter=True)
+    for i in range(STAGE_DOCS):
+        with warren:
+            warren.transaction()
+            index_document(warren, f"school education wind item{i} w{i % 5}",
+                           docid=f"d{i}")
+            warren.commit()
+    return warren
+
+
+def _search(warren, text="school education"):
+    from repro.train.serve import RetrievalServer
+    server = RetrievalServer(warren, k=5)
+    try:
+        return server.query(text, timeout=60)
+    finally:
+        server.close()
+
+
+def _one_doc_per_group(warren, groups):
+    from repro.dist.shard_router import shard_of
+    with warren:
+        docs = warren.annotations(":")
+    picks = {}
+    for i in range(len(docs)):
+        g = shard_of(int(docs.starts[i]))
+        if g in groups:
+            picks.setdefault(g, (int(docs.starts[i]), int(docs.ends[i])))
+    assert sorted(picks) == sorted(groups)
+    return [picks[g] for g in sorted(picks)]
+
+
+def _tag(warren, picks):
+    with warren:
+        warren.transaction()
+        for p, q in picks:
+            warren.annotate("tag:", p, q, 1.0)
+        warren.commit()
+
+
+def _span_counts():
+    return {labels["span"]: m.count
+            for labels, m in obs.registry().series("span_ms") if m.count}
+
+
+def test_span_ms_observes_every_closed_span():
+    tr = Tracer()
+    with pytest.raises(KeyError):
+        with tr.span("root"):
+            for _ in range(2):
+                with tr.span("child"):
+                    pass
+            with tr.span("boom"):
+                raise KeyError("x")
+    assert _span_counts() == {"root": 1, "child": 2, "boom": 1}
+    root = tr.last_trace("root").root
+    assert obs.registry().histogram("span_ms", span="root").sum == \
+        pytest.approx(root.duration_ms)
+    obs.registry().disable()             # tracer on, registry off
+    with tr.span("root"):
+        pass
+    assert obs.registry().histogram("span_ms", span="root").count == 1
+
+
+def test_sharded_search_stage_spans_and_phases(tmp_path):
+    warren = _sharded_warren(tmp_path)
+    try:
+        obs.registry().reset()
+        obs.tracer().reset()
+        assert _search(warren)
+    finally:
+        warren.close()
+    t = obs.tracer().last_trace("serve.batch")
+    tree = t.tree()
+    kids = [c["name"] for c in tree["children"]]
+    assert {"device_score", "merge"} <= set(kids)
+    scatters = [c for c in tree["children"] if c["name"] == "scatter"]
+    assert sorted(c["labels"]["group"] for c in scatters) == [0, 1, 2]
+    for c in scatters:           # the stats read runs on the replica
+        (read,) = c["children"]
+        assert read["name"] == "replica_read"
+        assert [k["name"] for k in read["children"]] == ["scatter.stats"]
+    # one span_ms observation per span of the trace, and no other
+    assert _span_counts() == dict(Tally(t.names()))
+    phases = {labels["phase"]: m.count
+              for labels, m in obs.registry().series("kernel_phase_ms")
+              if labels["kernel"] == "bm25_topk"}
+    assert phases["impacts"] == 1 and phases["compute"] == 1
+    assert phases["gather"] == 3 and phases["dispatch"] == 3
+
+
+def test_queue_wait_of_a_lone_request_is_the_coalescing_wait():
+    from repro.train.serve import BatcherConfig, MicroBatcher
+    b = MicroBatcher(lambda reqs: reqs,
+                     BatcherConfig(max_batch=8, max_wait_ms=50))
+    try:
+        assert b.submit("a").get(timeout=5) == "a"
+    finally:
+        b.close()
+    h = obs.registry().histogram("serve_queue_wait_ms")
+    assert h.count == 1 and h.sum >= 45.0
+
+
+def test_queue_wait_of_a_full_batch_is_near_zero():
+    from repro.train.serve import BatcherConfig, MicroBatcher
+    b = MicroBatcher(lambda reqs: reqs,
+                     BatcherConfig(max_batch=4, max_wait_ms=2000))
+    try:
+        handles = [b.submit(i) for i in range(4)]
+        assert [h.get(timeout=5) for h in handles] == [0, 1, 2, 3]
+    finally:
+        b.close()
+    h = obs.registry().histogram("serve_queue_wait_ms")
+    # launched as soon as it was full, long before the 2 s deadline
+    assert h.count == 4 and h.snapshot()["max"] < 100.0
+
+
+def test_h2d_bytes_are_the_packed_arrays(tmp_path, monkeypatch):
+    from repro.train import serve
+    sent = []
+    scorer = serve.bm25_topk
+
+    def spy(doc_idx, impacts, qmask, **kw):
+        sent.append(doc_idx.nbytes + impacts.nbytes + qmask.nbytes)
+        return scorer(doc_idx, impacts, qmask, **kw)
+
+    monkeypatch.setattr(serve, "bm25_topk", spy)
+    warren = _sharded_warren(tmp_path)
+    try:
+        obs.registry().reset()
+        assert _search(warren)
+    finally:
+        warren.close()
+    h = obs.registry().histogram("serve_h2d_bytes")
+    assert len(sent) == 3
+    assert h.count == 1 and h.sum == sum(sent)
+
+
+def test_two_group_commit_span_tree(tmp_path):
+    warren = _sharded_warren(tmp_path, replicas=2)
+    try:
+        picks = _one_doc_per_group(warren, (0, 1))
+        obs.registry().reset()
+        obs.tracer().reset()
+        _tag(warren, picks)
+        with warren:
+            assert len(warren.annotations("tag:")) == 2
+    finally:
+        warren.close()
+    tree = obs.tracer().last_trace("txn.commit").tree()
+    assert [c["name"] for c in tree["children"]] == ["txn.ready",
+                                                     "txn.publish"]
+    assert not tree["error"]
+    counts = _span_counts()
+    assert [counts.get(n) for n in ("txn.commit", "txn.ready",
+                                    "txn.publish")] == [1, 1, 1]
+
+
+def test_quorum_abort_closes_ready_with_error_and_no_publish(tmp_path):
+    from repro.dist.shard_router import QuorumError
+    warren = _sharded_warren(tmp_path, replicas=2)
+    try:
+        picks = _one_doc_per_group(warren, (0, 1))
+        warren.mark_failed(0, 0)             # group 0: 1/2 < quorum
+        obs.registry().reset()
+        obs.tracer().reset()
+        with pytest.raises(QuorumError):
+            _tag(warren, picks)
+    finally:
+        warren.close()
+    tree = obs.tracer().last_trace("txn.commit").tree()
+    assert tree["error"] is True
+    assert [c["name"] for c in tree["children"]] == ["txn.ready"]
+    assert tree["children"][0]["error"] is True
+    assert tree["children"][0]["labels"]["error"] == "QuorumError"
+    counts = _span_counts()
+    assert counts.get("txn.ready") == 1 and "txn.publish" not in counts
+
+
+def test_obs_disable_records_no_stage_metrics(tmp_path):
+    warren = _sharded_warren(tmp_path, replicas=2)
+    try:
+        picks = _one_doc_per_group(warren, (0, 1))
+        obs.registry().reset()
+        obs.tracer().reset()
+        obs.disable()
+        assert _search(warren)
+        _tag(warren, picks)
+    finally:
+        warren.close()
+    reg = obs.registry()
+    for fam in ("span_ms", "serve_queue_wait_ms", "serve_h2d_bytes",
+                "kernel_phase_ms"):
+        assert all(m.count == 0 for _, m in reg.series(fam)), fam
+    assert obs.tracer().traces() == []
+    assert obs.span("txn.commit") is obs.span("scatter.stats", group=1)
 
 
 # --------------------------------------------------------------------- #
