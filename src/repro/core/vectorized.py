@@ -195,13 +195,31 @@ def followed_by(a_s, a_e, b_s, b_e):
 # --------------------------------------------------------------------- #
 @functools.partial(jax.jit, static_argnames=("n_docs", "k"))
 def bm25_topk(doc_idx, impacts, qmask, n_docs: int, k: int):
-    """Batched exhaustive BM25.
+    """Batched exhaustive BM25: a float32 scatter-add of every impact into
+    one ``[n_docs]`` accumulator per query, then ``top_k`` of each.  The
+    rank of ``doc_idx`` selects the input form.
 
-    doc_idx  [Q, T, L] int32 padded with n_docs (scatter drop)
-    impacts  [Q, T, L] f32, zero where padded
-    qmask    [Q, T]    f32 per-query term weights (0 = absent term)
+    Postings form, one padded row per (query, term):
+      doc_idx  [Q, T, L] int32 padded with n_docs (scatter drop)
+      impacts  [Q, T, L] f32, zero where padded
+      qmask    [Q, T]    f32 per-query term weights (0 = absent term)
+
+    Compact form, every posting of the batch in one flat block:
+      doc_idx  [P] int32, ``q * n_docs + d`` for document d of query slot
+               q; padded with ``Q * n_docs`` (scatter drop)
+      impacts  [P] f32, already weighted
+      qmask    [Q, 1] f32, each query slot's weight (0 = empty slot)
+
+    Both lay out a query's accumulator the same way, so equal scores
+    come out lower document index first in either.
     returns  (scores [Q, k], ids [Q, k])
     """
+    if doc_idx.ndim == 1:
+        qp = qmask.shape[0]
+        acc = jnp.zeros((qp * n_docs,), jnp.float32)
+        acc = acc.at[doc_idx].add(impacts, mode="drop")
+        return jax.lax.top_k(acc.reshape(qp, n_docs) * qmask, k)
+
     def per_query(di, im, qm):
         acc = jnp.zeros((n_docs,), jnp.float32)
         contrib = (im * qm[:, None]).reshape(-1)

@@ -6,9 +6,10 @@ the block-impact format, and scoring runs through either the exhaustive
 device path or the Block-Max Pallas kernel.  Over a ``ShardedWarren`` it
 serves *natively*: each micro-batch fans out once per shard group (on the
 warren's scatter pool when async scatter is enabled), every group packs its
-own ``(doc_idx, impacts, qmask)`` block with GLOBAL collection statistics,
-per-group device ``bm25_topk`` dispatches overlap the next group's packing,
-and a global k-way merge yields exactly the single-index results.
+own compact block with GLOBAL collection statistics (every posting of the
+batch in one flat ``[P]`` array, no padded rows; ``bm25_topk``'s compact
+form), per-group device ``bm25_topk`` dispatches overlap the next group's
+packing, and a global k-way merge yields exactly the single-index results.
 
 LMServer wraps the transformer decode path with a KV cache and a simple
 continuous-batching slot scheduler.
@@ -22,6 +23,7 @@ import itertools
 import queue
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -29,9 +31,25 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import collection_stats, ranking
+from repro.core import collection_stats, ranking, vectorized
 from repro.core.vectorized import bm25_topk
 from repro.dist.parallel import ScatterTimings
+
+
+# compact blocks: a group's posting count rounds up to one of these
+# buckets, geometric steps of at most 1.5x from the floor, so a block is at
+# least two thirds postings once it is past the floor
+POSTINGS_FLOOR = 1024
+COMPILE_THREADS = 8
+
+
+def posting_buckets(limit: int) -> List[int]:
+    """The compact block sizes, ascending, up to the first >= ``limit``:
+    each 1.5x the last, rounded down to a multiple of 128."""
+    out = [POSTINGS_FLOOR]
+    while out[-1] < limit:
+        out.append(out[-1] * 3 // 2 // 128 * 128)
+    return out
 
 
 @dataclasses.dataclass
@@ -177,16 +195,21 @@ class RetrievalServer:
         self.max_postings = max_postings
         self._sharded = sharded_native and hasattr(warren, "map_groups")
         self.timings = ScatterTimings()
-        # device shapes already scored: a new (qp, tp, l, nb) tuple means
-        # the jitted scorer compiles again — the counter Autopilot watches
+        # device shapes already scored: a new shape tuple means the jitted
+        # scorer compiles again — the counter Autopilot watches
         # to tell shape-bucket churn from steady-state serving
         self._seen_shapes: set = set()
+        # compact blocks keep one row per batch slot, so their shapes vary
+        # only with the posting bucket and the accumulator width
+        batcher = batcher or BatcherConfig()
+        self._query_slots = self._pad_sizes(batcher.max_batch, 1, 1)[0]
+        self._warm_widths: set = set()
         if self._sharded:
             self.stats = None    # the native path re-scatters per batch
         else:
             with warren:
                 self.stats = collection_stats(warren)
-        self.batcher = MicroBatcher(self._handle, batcher or BatcherConfig())
+        self.batcher = MicroBatcher(self._handle, batcher)
 
     def refresh_stats(self) -> None:
         """Re-derive collection statistics from a fresh snapshot; queries
@@ -255,10 +278,11 @@ class RetrievalServer:
         are filtered by the ``s > 0`` result guard."""
         return 1 << max(max(n_docs, self.k) - 1, 0).bit_length()
 
-    def _note_shapes(self, qp: int, tp: int, l: int, nb: int) -> None:
+    def _note_shapes(self, *shape: int) -> None:
         """Count first sightings of a device shape bucket — each one is a
-        fresh XLA compile of the jitted scorer."""
-        key = (qp, tp, l, nb, self.k)
+        fresh XLA compile of the jitted scorer.  ``shape`` is (qp, tp, l,
+        nb) for the postings form, (qp, p, nb) for the compact form."""
+        key = (*shape, self.k)
         if key not in self._seen_shapes:
             self._seen_shapes.add(key)
             reg = obs.registry()
@@ -268,6 +292,29 @@ class RetrievalServer:
                     "distinct (batch, terms, postings, accumulator) device "
                     "shape buckets scored — each costs one XLA compile"
                 ).inc()
+
+    def _warm_compact(self, qp: int, nb: int) -> None:
+        """Compile the compact scorer for every posting bucket a batch of
+        ``qp`` query slots can fill at accumulator width ``nb``, the first
+        time a batch needs that width: the batches after it, whatever
+        their postings, then compile nothing."""
+        if (qp, nb) in self._warm_widths:
+            return
+        self._warm_widths.add((qp, nb))
+        sizes = posting_buckets(qp * self.max_terms
+                                * min(self.max_postings, nb))
+
+        def one(p: int) -> None:
+            vectorized.bm25_topk.lower(
+                jax.ShapeDtypeStruct((p,), jnp.int32),
+                jax.ShapeDtypeStruct((p,), jnp.float32),
+                jax.ShapeDtypeStruct((qp, 1), jnp.float32),
+                n_docs=nb, k=self.k).compile()
+
+        with ThreadPoolExecutor(COMPILE_THREADS) as ex:
+            list(ex.map(one, sizes))
+        for p in sizes:
+            self._note_shapes(qp, p, nb)
 
     # -- single-index path ------------------------------------------------- #
     def _handle_single(self, queries: List[str]
@@ -400,58 +447,77 @@ class RetrievalServer:
         with obs.phase_timer("bm25_topk", "impacts"):
             term_group = self._global_impacts(stems, per, lists, n_docs)
 
+        qp = max(self._query_slots, self._pad_sizes(qn, 1, 1)[0])
+
         def pack_group(g: int):
-            """This group's (doc_idx, impacts, qmask) block, or None when
-            the group has no documents or no postings for the batch."""
+            """This group's compact (doc_idx, impacts, qmask) block, its
+            accumulator width and its posting count, or None when the group
+            has no documents or no postings for the batch.  Every (query,
+            term) slot's postings go in, a stem twice in one query twice."""
             ng = per[g].n_docs
             if ng == 0:
                 return None
-            longest = max((len(per_g[g][0]) for per_g in term_group.values()
-                           if per_g is not None), default=0)
-            if longest == 0:    # nothing scored here: all-zero rows anyway
-                return None
-            qp, tp, lg = self._pad_sizes(
-                qn, max((len(row) for row in qfeatures), default=1), longest)
-            nb = self._acc_pad(ng)
-            self._note_shapes(qp, tp, lg, nb)
-            doc_idx = np.full((qp, tp, lg), nb, np.int32)
-            impacts = np.zeros((qp, tp, lg), np.float32)
-            qmask = np.zeros((qp, tp), np.float32)
+            parts, n = [], 0
             for qi, row in enumerate(qfeatures):
-                for ti, f in enumerate(row):
+                for f in row:
                     per_g = term_group[f]
-                    if per_g is None:
-                        continue
-                    qmask[qi, ti] = 1.0
-                    di, imp = per_g[g]
-                    if len(di):
-                        doc_idx[qi, ti, :len(di)] = di
-                        impacts[qi, ti, :len(di)] = imp
-            return doc_idx, impacts, qmask, nb
+                    if per_g is not None and len(per_g[g][0]):
+                        parts.append((qi, *per_g[g]))
+                        n += len(per_g[g][0])
+            if n == 0:          # nothing scored here: all-zero rows anyway
+                return None
+            nb = self._acc_pad(ng)
+            self._warm_compact(qp, nb)
+            p = posting_buckets(n)[-1]
+            doc_idx = np.empty(p, np.int32)
+            impacts = np.empty(p, np.float32)
+            pos = 0
+            for qi, di, imp in parts:
+                end = pos + len(di)
+                np.add(di, qi * nb, out=doc_idx[pos:end], casting="unsafe")
+                impacts[pos:end] = imp
+                pos = end
+            doc_idx[n:] = qp * nb
+            impacts[n:] = 0.0
+            qmask = np.zeros((qp, 1), np.float32)
+            qmask[:qn] = 1.0
+            return doc_idx, impacts, qmask, nb, n
 
         # pipelined scoring: jax dispatch is asynchronous, so group g's
         # device top-k computes while group g+1's block is being packed;
         # the np.asarray collection below blocks on all of them at once
         with obs.span("device_score"):
-            pending, h2d_bytes = [], 0
+            pending, h2d_bytes, postings, slots = [], 0, 0, 0
             for g in range(n_groups):
                 with obs.phase_timer("bm25_topk", "gather"):
                     blk = pack_group(g)
                 if blk is None:
                     pending.append(None)
                     continue
-                doc_idx, impacts, qmask, nb = blk
+                doc_idx, impacts, qmask, nb, n = blk
                 h2d_bytes += doc_idx.nbytes + impacts.nbytes + qmask.nbytes
+                postings += n
+                slots += len(doc_idx)
+                # host arrays go to the call itself, which copies all
+                # three at once: a jnp.asarray each waits out one copy's
+                # latency after another
                 with obs.phase_timer("bm25_topk", "dispatch"):
-                    pending.append(bm25_topk(
-                        jnp.asarray(doc_idx), jnp.asarray(impacts),
-                        jnp.asarray(qmask), n_docs=nb, k=k))
+                    pending.append(bm25_topk(doc_idx, impacts, qmask,
+                                             n_docs=nb, k=k))
             reg = obs.registry()
             if reg.enabled:
                 reg.histogram("serve_h2d_bytes",
                               "bytes copied to the device per micro-batch "
                               "(every group's packed blocks)",
                               lo=1.0, hi=1e10).observe(h2d_bytes)
+                reg.histogram("serve_scored_postings",
+                              "postings sent to the device per micro-batch "
+                              "(every group's compact blocks, no padding)",
+                              lo=1.0, hi=1e10).observe(postings)
+                reg.histogram("serve_scatter_slots",
+                              "slots the device scatter-adds per micro-batch, "
+                              "padding included",
+                              lo=1.0, hi=1e10).observe(slots)
             with obs.phase_timer("bm25_topk", "compute"):
                 group_res = [None if p is None
                              else (np.asarray(p[0]), np.asarray(p[1]))

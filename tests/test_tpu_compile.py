@@ -55,6 +55,23 @@ def test_bm25_topk_compiles_for_v5e(one_chip, n_docs):
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * q * n_docs * 4
 
 
+@pytest.mark.parametrize("p, n_docs", [(1024, 1 << 23),
+                                       (5_020_928, 1 << 14)])
+def test_bm25_topk_compact_compiles_for_v5e(one_chip, p, n_docs):
+    """The served scorer's compact form: 16 query slots, from the smallest
+    posting bucket into a 2^23 accumulator to the largest bucket a batch of
+    16 queries x 16 terms can fill at a 2^14 one."""
+    q = 16
+    fn = jax.jit(lambda d, i, m: bm25_topk(d, i, m, n_docs=n_docs, k=10))
+    compiled = fn.lower(_spec((p,), jnp.int32, one_chip),
+                        _spec((p,), jnp.float32, one_chip),
+                        _spec((q, 1), jnp.float32, one_chip)).compile()
+    # about three [Q, n_docs] f32 arrays (the flat accumulator, its
+    # [Q, n_docs] layout and top-k's working copy): 1.5 GiB at 2^23
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        3.5 * q * n_docs * 4 + 8 * p
+
+
 def test_bm25_blockmax_compiles_for_v5e(one_chip):
     """The block-max kernel at a real width: 8 terms over 2^15 blocks of
     128 documents (4M documents) compiles through Mosaic, not the
