@@ -1,15 +1,19 @@
 """Find a cell's pieces by name: its entry in ``BENCHMARK.json``, its
 configuration (``bench/configs/<config>.json``), its traffic mix
-(``bench/traffic/<traffic>.json``), its metrics and their readers
-(``bench/metrics/<name before the first dot>.py``, a ``read(ctx)``
-function each).  A new cell, configuration, mix or metric is a new file
-and a new entry; nothing here changes."""
+(``bench/traffic/<traffic>.json``), its configuration's deployment kind
+(``bench/kinds/<kind>.py`` or the package ``bench/kinds/<kind>/``, named
+by the configuration's ``"kind"``; ``harness`` says what a kind provides),
+its metrics and their readers (``bench/metrics/<name before the first
+dot>.py``, a ``read(ctx)`` function each).  A new cell, configuration,
+kind, mix or metric is a new file and a new entry; nothing here changes."""
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Callable, List
 
@@ -66,3 +70,14 @@ def reader(metric: str, root: Path = ROOT) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def kind(name: str, root: Path = ROOT):
+    """The deployment kind ``name``, imported as ``kinds.<name>`` from
+    ``root``'s ``bench/kinds`` (a namespace package, so the checkout's
+    own kinds stay found beside a test root's)."""
+    bench = str(root / "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    importlib.invalidate_caches()       # a kind written since the last look
+    return importlib.import_module(f"kinds.{name}")

@@ -52,7 +52,7 @@ class _Handle:
     def get(self, block=True, timeout=None):
         import numpy as np
 
-        import reference
+        from kinds.passages import reference
         ans = self._handle.get(block, timeout)
         if not ans:
             return ans

@@ -1,13 +1,12 @@
 """Drive a schedule through the program's entry points and time every
 request on the client's clock.
 
-Reads go to ``RetrievalServer.batcher.submit``; an open-loop request is
-timed from when it was due, so a stalled server also delays the requests
-queued behind it.  The batcher serves batches one after another, in
-arrival order, so a single waiter thread collects answers in submission
-order.  Updates go to writer threads, each with its own
-``ShardedWarren.clone()``; passage ``p`` always goes to writer
-``p % writers``, so the updates of one passage commit in schedule order.
+Reads go through a ``send`` of the deployment kind's (its ``submit``,
+to the program's ``MicroBatcher``); an open-loop request is timed from
+when it was due, so a stalled server also delays the requests queued
+behind it.  The batcher serves batches one after another, in arrival
+order, so a single waiter thread collects answers in submission order.
+Writes go to the kind's writers, which record them in a ``Writes``.
 """
 
 from __future__ import annotations
@@ -42,14 +41,6 @@ class Writes:
     new: List[Optional[Tuple[int, int]]]   # committed (lo, hi) of the version
     old: List[Tuple[int, int]]             # (lo, hi) it erased
     ranks: List[np.ndarray]
-
-
-class Versions:
-    """Where each passage's current version lives, as the writers see it:
-    ``addr[p] = (lo, hi)``."""
-
-    def __init__(self, addrs: np.ndarray):
-        self.addr = {p: (int(lo), int(hi)) for p, (lo, hi) in enumerate(addrs)}
 
 
 class BatchClock:
@@ -89,80 +80,11 @@ def _noop_span(name):
     return contextlib.nullcontext()
 
 
-class Writer(threading.Thread):
-    """Commits updates from its queue, one transaction each.  ``publish_t``
-    is when the commit's second phase began (see ``watch_publish``)."""
-
-    def __init__(self, warren, versions: Versions, writes: Writes,
-                 lock: threading.Lock, text_of: Callable, span=_noop_span):
-        super().__init__(daemon=True)
-        self.warren = warren
-        self.versions = versions
-        self.writes = writes
-        self.lock = lock
-        self.text_of = text_of
-        self.span = span
-        self.publish_t = np.nan
-        self.q: "queue.Queue" = queue.Queue()
-        self.errors: List[BaseException] = []
-
-    def run(self):
-        while True:
-            item = self.q.get()
-            if item is None:
-                return
-            try:
-                self._commit(*item)
-            finally:
-                self.q.task_done()
-
-    def _commit(self, due, upd):
-        from repro.core import ranking
-        old = self.versions.addr[upd.passage]
-        text = self.text_of(upd.ranks)
-        new, commit_start, ack = None, np.nan, np.nan
-        self.publish_t = np.nan
-        try:
-            with self.warren:
-                self.warren.transaction()
-                self.warren.erase(*old)
-                lo, hi = ranking.index_document(self.warren, text)
-                commit_start = time.perf_counter()
-                with self.span("bench.commit"):
-                    remap = self.warren.commit()
-                ack = time.perf_counter()
-            new = (remap(lo), remap(hi))
-            self.versions.addr[upd.passage] = new
-        except Exception as e:     # counted as failed; the run goes on
-            self.errors.append(e)
-        with self.lock:
-            w = self.writes
-            w.due.append(due)
-            w.commit_start.append(commit_start)
-            w.publish.append(self.publish_t)
-            w.ack.append(ack)
-            w.new.append(new)
-            w.old.append(old)
-            w.ranks.append(upd.ranks)
-
-
-def watch_publish(warren, writers: List[Writer]) -> None:
-    """Time the start of each commit's second phase, when replicas begin to
-    publish, through the warren family's ``mid_commit`` hook (called per
-    touched group between the two phases, in the committing thread)."""
-    by_clone = {id(w.warren): w for w in writers}
-
-    def mid_commit(clone, group):
-        w = by_clone.get(id(clone))
-        if w is not None and np.isnan(w.publish_t):
-            w.publish_t = time.perf_counter()
-    warren.hooks["mid_commit"] = mid_commit
-
-
-def open_loop(server, schedule, pool_texts: List[str], t0: float,
-              writers: List[Writer], span=_noop_span) -> Tuple[Reads, dict]:
-    """Send ``schedule``'s requests at ``t0 + due``; return the reads and
-    the generator's lateness.  Writes land in the writers' ``Writes``."""
+def open_loop(send: Callable, schedule, t0: float, write: Callable = None,
+              span=_noop_span) -> Tuple[Reads, dict]:
+    """Send ``schedule``'s requests at ``t0 + due``, a read ``q`` as
+    ``send(q)`` (a handle with ``get``), a write as ``write(at, payload)``;
+    return the reads and the generator's lateness."""
     is_read = schedule.query >= 0
     n = int(is_read.sum())
     reads = Reads(schedule.query[is_read].copy(), schedule.due[is_read] + t0,
@@ -197,13 +119,12 @@ def open_loop(server, schedule, pool_texts: List[str], t0: float,
         late[j] = now - at
         if q >= 0:
             with span("bench.submit"):
-                h = server.batcher.submit(pool_texts[q])
+                h = send(q)
             reads.sent[r] = now
             handles.put((r, h))
             r += 1
         else:
-            upd = schedule.updates[j]
-            writers[upd.passage % len(writers)].q.put((at, upd))
+            write(at, schedule.updates[j])
     handles.put(None)
     waiter.join(timeout=GRACE_S + 5)
     return reads, {"late_p50_ms": 1e3 * float(np.median(late)) if len(late)
@@ -214,10 +135,10 @@ def open_loop(server, schedule, pool_texts: List[str], t0: float,
                    else 0.0}
 
 
-def closed_loop(server, plan, pool_texts: List[str], t0: float,
-                seconds: float) -> Tuple[Reads, dict]:
-    """``plan.clients`` callers, each sending its next query when the last
-    returns, from ``t0`` until ``t0 + seconds``."""
+def closed_loop(send: Callable, plan, t0: float, seconds: float
+                ) -> Tuple[Reads, dict]:
+    """``plan.clients`` callers, each sending its next read (``send(q)``)
+    when the last returns, from ``t0`` until ``t0 + seconds``."""
     per_client: Dict[int, list] = {}
     t_end = t0 + seconds
 
@@ -231,7 +152,7 @@ def closed_loop(server, plan, pool_texts: List[str], t0: float,
                 break
             q = int(qs[j % len(qs)])
             j += 1
-            h = server.batcher.submit(pool_texts[q])
+            h = send(q)
             try:
                 ans = h.get(timeout=max(0.0, t_end + GRACE_S
                                         - time.perf_counter()))

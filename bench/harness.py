@@ -3,32 +3,48 @@
 ``run`` is what ``bench/run.py`` calls.  Its ``platform`` and ``hooks``
 arguments exist for ``bench/tests``, which rehearse a run on the CPU and
 break the program underneath it; the command line always asks for a TPU.
+
+What depends on what a configuration deploys is its kind's
+(``"kind"`` in the configuration's file; ``cells.kind`` loads
+``bench/kinds/<kind>``).  A kind provides:
+
+- ``REQUESTS``: the request kinds it serves, in order, each ``"read"`` or
+  ``"write"``; a mix that names another is refused;
+- ``open(cell, state_dir, log_dir, span)``: the deployment, built or
+  restored, as a :class:`Deployment`;
+- ``payloads(mix, config, counts, stream)``: what each of ``counts[k]``
+  requests of kind ``k`` carries, as lists; ``generate`` deals them out
+  in the order and at the times the seed draws;
+- ``submit(server, payload)``: send a read, returning a handle with
+  ``get``; writes go to the deployment's ``write``;
+- ``warm(session, slack)``: compile every device shape the session's
+  pool of reads can reach; returns what it warmed, for the log;
+- ``check(cell, session, window, seed)``: ``{name: {"value", "limit"}}``,
+  each number compared beside its limit; a run is correct where every
+  value is within its limit;
+- ``context(session)``: the fields the per-layer readers read beyond the
+  window's own (``Context``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import gc
-import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 import cells
-import check
-import corpus as corpus_mod
-import deploy
 import device
 import drive
 import generate
 import trace as trace_mod
 
 WARM_STREAM, WINDOW_STREAM = 1, 0
-COMPILE_THREADS = 8
-RTOL = 1e-5
+# snapshots, durable logs and traces
+STATE = cells.BENCH / ".state"
 
 
 def log(msg: str) -> None:
@@ -80,116 +96,34 @@ def trace_labels(enabled: bool):
         obs.span, obs.phase_timer = span0, phase0
 
 
-def device_shapes(server, warren, pool: List[List[int]], slack: int) -> set:
-    """Every ``(qp, tp, l, nb)`` block shape the server can score for
-    batches drawn from ``pool``, using the server's own bucketing, when
-    no posting list or group grows or shrinks by more than ``slack``
-    documents."""
-    from repro.core import ranking
-    feats = [[ranking.TF_PREFIX + ranking.porter_stem(corpus_mod.word(r))
-              for r in q] for q in pool]
-    uniq = sorted({f for q in feats for f in q})
-    with warren:
-        per_group = warren.map_groups(lambda w: (
-            len(w.annotations(ranking.DOC_FEATURE)),
-            [len(w.annotations(f)) for f in uniq]))
-    max_batch = server.batcher.cfg.max_batch
-    qps = {server._pad_sizes(n, 1, 1)[0] for n in range(1, max_batch + 1)}
-    tps = {server._pad_sizes(1, len(q[:server.max_terms]), 1)[1]
-           for q in feats}
-    shapes = set()
-    for n_g, dfs in per_group:
-        df = dict(zip(uniq, dfs))
-        ls = set()
-        for q in feats:
-            longest = max(df[f] for f in q)
-            if longest == 0 and not slack:
-                continue
-            for d in range(max(1, longest - slack), longest + slack + 1, 64):
-                ls.add(server._pad_sizes(1, 1, d)[2])
-            ls.add(server._pad_sizes(1, 1, longest + slack)[2])
-        nbs = {server._acc_pad(n)
-               for n in range(max(0, n_g - slack), n_g + slack + 1)}
-        for qp in qps:
-            for tp in tps:
-                for l in ls:
-                    for nb in nbs:
-                        shapes.add((qp, tp, l, nb))
-    return shapes
+class Deployment:
+    """What a kind's ``open`` returns: the system under test (``server``,
+    serving reads through the program's ``MicroBatcher`` as
+    ``server.batcher`` and keeping ``server.timings``), its set-up
+    timings, and the writes its writers record.  A kind that serves
+    writes overrides ``write``, ``drain``, ``errors`` and ``stop``."""
 
-
-def compile_shapes(shapes: set, k: int) -> None:
-    """Compile the served scorer for every shape, several at once, then
-    run each once so the window finds all of them ready."""
-    import jax
-    import jax.numpy as jnp
-    from repro.train import serve
-
-    def one(shape):
-        qp, tp, l, nb = shape
-        serve.bm25_topk.lower(
-            jax.ShapeDtypeStruct((qp, tp, l), jnp.int32),
-            jax.ShapeDtypeStruct((qp, tp, l), jnp.float32),
-            jax.ShapeDtypeStruct((qp, tp), jnp.float32),
-            n_docs=nb, k=k).compile()
-
-    with ThreadPoolExecutor(COMPILE_THREADS) as ex:
-        list(ex.map(one, sorted(shapes)))
-    out = None
-    for qp, tp, l, nb in sorted(shapes):
-        out = serve.bm25_topk(
-            jnp.asarray(np.full((qp, tp, l), nb, np.int32)),
-            jnp.asarray(np.zeros((qp, tp, l), np.float32)),
-            jnp.asarray(np.zeros((qp, tp), np.float32)), n_docs=nb, k=k)
-    if out is not None:
-        jax.block_until_ready(out)
-
-
-class Traffic:
-    """Drives planned requests through the server (and writers)."""
-
-    def __init__(self, cell, server, warren, addrs, pool_texts, span):
-        self.cell, self.server, self.warren = cell, server, warren
-        self.pool_texts = pool_texts
-        self.span = span
-        self.versions = drive.Versions(addrs)
+    def __init__(self, server, times: dict):
+        self.server, self.times = server, times
         self.writes = drive.Writes([], [], [], [], [], [], [])
-        self.lock = threading.Lock()
-        self.writers: List[drive.Writer] = []
-        mix = cell.mix
-        if generate.shares(mix).get("update", 0.0) > 0:
-            def text_of(ranks):
-                return " ".join(corpus_mod.word(int(r)) for r in ranks)
-            self.writers = [drive.Writer(warren.clone(), self.versions,
-                                         self.writes, self.lock, text_of,
-                                         span)
-                            for _ in range(mix["update"]["writers"])]
-            drive.watch_publish(warren, self.writers)
-            for w in self.writers:
-                w.start()
 
-    def drive(self, plan, seconds: float, t0: float):
-        if isinstance(plan, generate.ClosedPlan):
-            return drive.closed_loop(self.server, plan, self.pool_texts, t0,
-                                     seconds)
-        return drive.open_loop(self.server, plan, self.pool_texts, t0,
-                               self.writers, self.span)
+    def write(self, at: float, payload: Any) -> None:
+        raise NotImplementedError("this deployment takes no writes")
 
     def drain(self, timeout: float) -> bool:
-        """Wait until every queued update is committed or failed."""
-        end = time.perf_counter() + timeout
-        while any(w.q.unfinished_tasks for w in self.writers):
-            if time.perf_counter() > end:
-                return False
-            time.sleep(0.01)
+        """Wait until every write handed over is committed or failed."""
         return True
 
-    def close(self):
-        for w in self.writers:
-            w.q.put(None)
-        for w in self.writers:
-            w.join(timeout=drive.GRACE_S)
-        self.writers = []
+    @property
+    def errors(self) -> List[BaseException]:
+        return []
+
+    def stop(self) -> None:
+        """Stop serving; the state stays for the check."""
+        self.server.close()
+
+    def close(self) -> None:
+        self.stop()
 
 
 class FullCollections:
@@ -235,47 +169,35 @@ class Session:
                  platform: str = "tpu", root: Path = cells.ROOT,
                  hooks: Optional[dict] = None):
         cell = self.cell = cells.load(workload, root)
+        self.kind = cells.kind(cell.config["kind"], root)
+        generate.shares(cell.mix, self.kind)
         self.dev = device.check(cell.chips, platform)
         log(f"device: {self.dev}")
         log(f"compile cache: {device.enable_cache()}")
         import jax
         from repro import obs
-        from repro.train.serve import BatcherConfig, RetrievalServer
         self.compiles = device.CompileCounter()
         obs.enable() if traced else obs.disable()
-        cfg = cell.config
-        srv, dep = cfg["server"], cfg["deployment"]
-        self.corpus = corpus_mod.make_corpus(cfg)
-        # every query the session's plans send, in order of registration
-        self.pool: List[List[int]] = []
-        self.pool_texts: List[str] = []
-        self.log_dir = (deploy.STATE / "logs" / cell.name
-                        if dep["durable_log"] else None)
-        self.warren, self.addrs, times = deploy.open_deployment(
-            cfg, self.corpus, self.log_dir)
-        log(f"deployment: {times}")
-        self.server = RetrievalServer(
-            self.warren, k=srv["k"], max_terms=srv["max_terms"],
-            max_postings=srv["max_postings"],
-            batcher=BatcherConfig(max_batch=srv["max_batch"],
-                                  max_wait_ms=srv["max_wait_ms"]))
+        # every read the session's plans send, in order of registration
+        self.pool: List[Any] = []
+        self.span = ((lambda name: jax.profiler.TraceAnnotation(name))
+                     if traced else drive._noop_span)
+        self.dep = self.kind.open(cell, STATE, STATE / "logs" / cell.name,
+                                  self.span)
+        log(f"deployment: {self.dep.times}")
+        self.server = self.dep.server
         self.clock = drive.BatchClock(self.server.batcher)
         if hooks and "server" in hooks:
             self.server = hooks["server"](self.server)
-        span = ((lambda name: jax.profiler.TraceAnnotation(name)) if traced
-                else drive._noop_span)
-        self.traffic = Traffic(cell, self.server, self.warren, self.addrs,
-                               self.pool_texts, span)
 
     def plan(self, seed: int, seconds: float, stream: int,
              mix: Optional[dict] = None):
         """The requests of one window of ``mix`` (the cell's by default),
-        its queries added to the session's pool."""
-        p = generate.plan(mix or self.cell.mix, self.cell.config, self.corpus,
+        its reads added to the session's pool."""
+        p = generate.plan(mix or self.cell.mix, self.kind, self.cell.config,
                           seed, seconds, stream)
         off = len(self.pool)
         self.pool.extend(p.queries)
-        self.pool_texts.extend(corpus_mod.query_text(q) for q in p.queries)
         if isinstance(p, generate.ClosedPlan):
             p.order = p.order + off
         else:
@@ -283,22 +205,21 @@ class Session:
         return p
 
     def warm(self, plan, slack: int) -> None:
-        """Compile every device shape that the queries planned so far can
-        reach while no list or group changes by more than ``slack``
-        documents, then drive ``plan``, the warm-up's own requests."""
+        """Compile every device shape that the reads planned so far can
+        reach while the deployment changes by no more than ``slack``
+        writes, then drive ``plan``, the warm-up's own requests."""
         t0 = time.perf_counter()
-        shapes = device_shapes(self.server, self.warren, self.pool, slack)
-        compile_shapes(shapes, self.cell.config["server"]["k"])
+        what = self.kind.warm(self, slack)
         c = self.compiles
-        log(f"warm-up: {len(shapes)} device shapes in "
+        log(f"warm-up: {what} in "
             f"{time.perf_counter() - t0:.3f}s ({c.compiles} backend "
             f"compiles, {c.cache_hits} persistent cache hits)")
         before = c.compiles
-        reads, _ = self.traffic.drive(plan, self.cell.mix["warmup_s"],
-                                      time.perf_counter() + 0.05)
-        self.traffic.drain(drive.GRACE_S)
+        reads, _ = self.drive(plan, self.cell.mix["warmup_s"],
+                              time.perf_counter() + 0.05)
+        self.dep.drain(drive.GRACE_S)
         log(f"warm-up traffic: {len(reads.done)} reads, "
-            f"{len(self.traffic.writes.ack)} writes, "
+            f"{len(self.dep.writes.ack)} writes, "
             f"{c.compiles - before} compiles")
         # the restored heap is millions of objects: one full collection
         # now, as a server long past its start would have had, so that the
@@ -307,18 +228,29 @@ class Session:
         gc.collect()
         log(f"full collection after set-up: {time.perf_counter() - t0:.3f}s")
 
+    def drive(self, plan, seconds: float, t0: float):
+        """Send ``plan``'s requests from ``t0``: its reads through the
+        kind's ``submit``, its writes to the deployment."""
+        submit, server, pool = self.kind.submit, self.server, self.pool
+
+        def send(q):
+            return submit(server, pool[q])
+        if isinstance(plan, generate.ClosedPlan):
+            return drive.closed_loop(send, plan, t0, seconds)
+        return drive.open_loop(send, plan, t0, self.dep.write, self.span)
+
     def window(self, plan, seconds: float, traced: bool = False
                ) -> "Window":
         """Drive ``plan`` for ``seconds``."""
         import jax
-        traffic, server = self.traffic, self.server
-        w_lo = len(traffic.writes.ack)
+        dep, server = self.dep, self.server
+        w_lo = len(dep.writes.ack)
         b_lo = len(self.clock.batches)
         compiles_before = self.compiles.compiles
         timings0 = server.timings.snapshot()
         batch0 = _obs_series("serve_batch_size")
         phase0 = _obs_series("kernel_phase_ms")
-        trace_dir = deploy.STATE / "trace" / self.cell.name
+        trace_dir = STATE / "trace" / self.cell.name
         if traced:
             import shutil
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -330,9 +262,9 @@ class Session:
         t0 = time.perf_counter() + 0.05
         with trace_labels(traced), span_ctx(traced, "bench.window"), \
                 FullCollections() as full:
-            reads, late = traffic.drive(plan, seconds, t0)
-            traffic.drain(max(1.0, t0 + seconds + drive.GRACE_S
-                              - time.perf_counter()))
+            reads, late = self.drive(plan, seconds, t0)
+            dep.drain(max(1.0, t0 + seconds + drive.GRACE_S
+                          - time.perf_counter()))
         if traced:
             jax.profiler.stop_trace()
         win = Window(self, reads, w_lo, seconds, t0, late)
@@ -346,29 +278,25 @@ class Session:
         log(f"full collections in the window: {len(full.pauses)}, "
             f"seconds {[round(p, 3) for p in full.pauses]}")
         log(f"window: {len(reads.done)} reads, "
-            f"{len(traffic.writes.ack) - w_lo} writes; generator lateness "
+            f"{len(dep.writes.ack) - w_lo} writes; generator lateness "
             f"{late}; compiles in the window {win.compiles}"
             + (f" {self.compiles.names[compiles_before:]}"
                if win.compiles else ""))
         log(f"requests: {win.attempted} attempted, {win.failed} failed; "
             + ", ".join(f"{k} {v:.3f}" for k, v in win.e2e.items()))
-        errors = [e for w in traffic.writers for e in w.errors]
+        errors = dep.errors
         if errors:
             log(f"writes failed: {len(errors)}, first: {errors[0]!r}")
         return win
 
     def stop_serving(self) -> None:
-        self.traffic.close()
-        self.server.close()
+        self.dep.stop()
 
     def check(self, win: "Window", seed: int) -> dict:
-        return check.run(self.cell, self.corpus, self.pool, self.addrs,
-                         win.reads, win.pins, self.traffic.writes,
-                         self.warren, seed, RTOL, self.log_dir)
+        return self.kind.check(self.cell, self, win, seed)
 
     def close(self) -> None:
-        self.stop_serving()
-        self.warren.close()
+        self.dep.close()
 
 
 class Window:
@@ -377,7 +305,7 @@ class Window:
     def __init__(self, session, reads, w_lo, seconds, t0, late):
         self.reads, self.w_lo, self.seconds = reads, w_lo, seconds
         self.late = late
-        writes = session.traffic.writes
+        writes = session.dep.writes
         ack = np.array(writes.ack[w_lo:], dtype=np.float64)
         due = np.array(writes.due[w_lo:], dtype=np.float64)
         ok_w = ~np.isnan(ack)
@@ -419,11 +347,10 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     if traced:
         red = trace_mod.reduce(trace_mod.find_xplane(win.trace_dir))
         dev.update(busy_s=red["busy_s"], window_s=red["window_s"])
-        ctx = Context(reads=win.reads, writes=s.traffic.writes,
-                      w_lo=win.w_lo, pool=s.pool,
-                      corpus_df=corpus_mod.document_frequency(s.corpus),
+        ctx = Context(reads=win.reads, writes=s.dep.writes, w_lo=win.w_lo,
                       batch=win.batch, phase=win.phase, timings=win.timings,
-                      trace=red, peaks=device.peaks(s.dev["kind"]))
+                      trace=red, peaks=device.peaks(s.dev["kind"]),
+                      **s.kind.context(s))
         metrics = {}
         for m in s.cell.per_layer:
             v = cells.reader(m["name"], root)(ctx)
@@ -452,7 +379,9 @@ def span_ctx(traced: bool, name: str):
 
 
 class Context:
-    """What a per-layer reader may read; see ``bench/metrics``."""
+    """What a per-layer reader may read; see ``bench/metrics``.  The
+    kind's ``context`` adds its own fields, ``kernel`` among them: the
+    device kernel whose ``kernel_phase_ms`` phases ``phase_ms`` reads."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -463,6 +392,6 @@ class Context:
 
     def phase_ms(self, phase: str) -> Optional[float]:
         for labels, (c, s) in self.phase.items():
-            if dict(labels) == {"kernel": "bm25_topk", "phase": phase}:
+            if dict(labels) == {"kernel": self.kernel, "phase": phase}:
                 return s
         return None

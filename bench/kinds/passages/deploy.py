@@ -9,9 +9,9 @@ inside one ``ShardedWarren`` transaction per group: a bulk load).  The four
 groups are built at once, each in a process of its own that never touches
 JAX, and each hands back its committed segments.  The snapshot holds them
 pickled, with the routing table and the address pair of every passage,
-under ``bench/.state/`` keyed by the corpus, the number of groups and a
-hash of the program's sources and of this build, so a changed program never
-restores another program's index.
+in the run's state directory (``bench/.state/``) keyed by the corpus, the
+number of groups and a hash of the program's sources and of this build,
+so a changed program never restores another program's index.
 
 Later runs restore it: every replica of a group gets its own copy of the
 group's segments, as ``CheckpointManager.restore_index_replicas`` makes
@@ -37,12 +37,12 @@ from typing import Tuple
 
 import numpy as np
 
-import corpus as corpus_mod
-from corpus import CORPUS_KEYS, Corpus
+from . import corpus as corpus_mod
+from .corpus import CORPUS_KEYS, Corpus
 
-BENCH = Path(__file__).resolve().parent
+HERE = Path(__file__).resolve().parent
+BENCH = HERE.parents[1]
 ROOT = BENCH.parent
-STATE = BENCH / ".state"
 
 
 def source_hash() -> str:
@@ -50,17 +50,17 @@ def source_hash() -> str:
     and the benchmark's corpus generator and build."""
     h = hashlib.sha256()
     files = sorted((ROOT / "src" / "repro").rglob("*.py"))
-    for p in files + [BENCH / "corpus.py", BENCH / "deploy.py"]:
+    for p in files + [HERE / "corpus.py", HERE / "deploy.py"]:
         h.update(str(p.relative_to(ROOT)).encode())
         h.update(p.read_bytes())
     return h.hexdigest()[:16]
 
 
-def snapshot_dir(config: dict) -> Path:
+def snapshot_dir(config: dict, state: Path) -> Path:
     key = json.dumps({"corpus": {k: config[k] for k in CORPUS_KEYS},
                       "n_shards": config["deployment"]["n_shards"],
                       "src": source_hash()}, sort_keys=True)
-    return STATE / ("snapshot-" + hashlib.sha256(key.encode()).hexdigest()[:16])
+    return state / ("snapshot-" + hashlib.sha256(key.encode()).hexdigest()[:16])
 
 
 def append_groups(corpus: Corpus, n_shards: int) -> np.ndarray:
@@ -107,8 +107,10 @@ def build_snapshot(config: dict, corpus: Corpus, out: Path) -> None:
     tmp.mkdir(parents=True)
     (tmp / "config.json").write_text(json.dumps(config))
     # the builders never touch the chip: this process may hold it
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    procs = [subprocess.Popen([sys.executable, str(Path(__file__)),
+    path = [str(BENCH), str(ROOT / "src"), os.environ.get("PYTHONPATH")]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(filter(None, path)))
+    procs = [subprocess.Popen([sys.executable, "-m", __name__,
                                str(tmp / "config.json"), str(g),
                                str(tmp / f"group{g:02d}.pkl")], env=env)
              for g in range(n_shards)]
@@ -129,22 +131,22 @@ def build_snapshot(config: dict, corpus: Corpus, out: Path) -> None:
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def open_deployment(config: dict, corpus: Corpus, log_dir: Path = None
-                    ) -> Tuple[object, np.ndarray, dict]:
+def open_deployment(config: dict, corpus: Corpus, state: Path,
+                    log_dir: Path = None) -> Tuple[object, np.ndarray, dict]:
     """The configuration's ``ShardedWarren``, restored from the snapshot
-    (built first when absent), with its passage address table and a dict
-    of set-up timings.  With ``log_dir`` every replica commits to a durable
-    transaction log of its own there, started empty."""
+    in ``state`` (built first when absent), with its passage address table
+    and a dict of set-up timings.  With ``log_dir`` every replica commits
+    to a durable transaction log of its own there, started empty."""
     from repro.core.featurizer import JsonFeaturizer
     from repro.core.tokenizer import Utf8Tokenizer
     from repro.dist.shard_router import ShardedWarren
 
     dep = config["deployment"]
-    snap = snapshot_dir(config)
+    snap = snapshot_dir(config, state)
     times = {}
     if not (snap / "passage_addrs.npy").exists():
         t0 = time.perf_counter()
-        STATE.mkdir(exist_ok=True)
+        state.mkdir(parents=True, exist_ok=True)
         build_snapshot(config, corpus, snap)
         times["build_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
@@ -196,6 +198,5 @@ def _load_groups(snap: Path, dep: dict, log_dir, tokenizer, featurizer):
 
 if __name__ == "__main__":
     # one group of a snapshot build: <config.json> <group> <out.pkl>
-    sys.path.insert(0, str(ROOT / "src"))
     build_group(json.loads(Path(sys.argv[1]).read_text()), int(sys.argv[2]),
                 Path(sys.argv[3]))

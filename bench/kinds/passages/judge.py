@@ -38,11 +38,16 @@ states is counted on stderr, not judged.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 import generate
-import reference
+from harness import log
 
+from . import reference
+
+RTOL = 1e-5             # float32 sums of float32 impacts
 WRITE_SAMPLE = 1000     # reads judged in a cell with writes, drawn by seed
 MAX_STATES = 4096       # states a read may have seen, tried in full
 
@@ -51,8 +56,12 @@ def _limit(value, limit) -> dict:
     return {"value": value, "limit": limit}
 
 
-def run(cell, corpus, pool, addrs, reads, pins, writes, warren, seed: int,
-        rtol: float, log_dir=None) -> dict:
+def run(cell, session, window, seed: int) -> dict:
+    """The checks of ``window``, a window driven through ``session``."""
+    t0 = time.perf_counter()
+    dep, pool, reads, pins = (session.dep, session.pool, window.reads,
+                              window.pins)
+    corpus, addrs, writes, rtol = dep.corpus, dep.addrs, dep.writes, RTOL
     k = cell.config["server"]["k"]
     bm = cell.config["bm25"]
     n = corpus.n
@@ -89,14 +98,14 @@ def run(cell, corpus, pool, addrs, reads, pins, writes, warren, seed: int,
     else:
         wrong, gap, judged, unjudged = _judge_with_writes(
             pool, reads, pins, writes, model, post, n, vid_of, seed, k,
-            rtol, named, warren.routing)
-        checks.update(_replicas(warren, addrs, writes, log_dir))
+            rtol, named, dep.warren.routing)
+        checks.update(_replicas(dep.warren, addrs, writes, dep.log_dir))
     checks["answers_wrong"] = _limit(wrong, 0)
     checks["score_gap"] = _limit(gap, rtol)
-    from harness import log
     log(f"check: {judged} distinct answers judged"
         + (f", {unjudged} reads with too many writes in flight"
-           if unjudged else ""))
+           if unjudged else "")
+        + f" in {time.perf_counter() - t0:.3f}s")
     order = ["unanswered", "answers_wrong", "score_gap", "replica_doc_diff",
              "durable_diff"]
     return {name: checks[name] for name in order if name in checks}

@@ -3,13 +3,13 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up restores (or, the first time in a checkout, builds) the cell's
-deployment, starts ``RetrievalServer``, compiles every device shape the
-cell's traffic can reach, and replays the mix for a few seconds.  The
-window then drives the mix for ``--seconds``.  Afterwards every answer is
-checked against the plain reference (``reference.py``).  The last line of
-stdout is the result: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` (and ``breakdown`` with ``--trace 1``), then ``checks``, each
-compared number beside its limit.  Without a TPU it exits non-zero and
+deployment through its kind (``bench/kinds``), compiles every device
+shape the cell's traffic can reach, and replays the mix for a few
+seconds.  The window then drives the mix for ``--seconds``.  Afterwards
+the kind checks what was served against its plain reference.  The last
+line of stdout is the result: ``correct``, ``attempted``, ``failed``,
+``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``), then
+``checks``, each compared number beside its limit.  Without a TPU it exits non-zero and
 prints no result.
 """
 

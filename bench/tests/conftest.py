@@ -18,8 +18,8 @@ for p in (BENCH, ROOT / "src"):
 def tiny_root(tmp_path_factory):
     """A checkout-shaped directory holding the real ``BENCHMARK.json`` and
     mixes, with every configuration cut to 400 passages and every mix to a
-    few seconds at a low rate, so a run fits the CPU, and one more cell,
-    closed-loop, made of a mix file and entries alone."""
+    few seconds at a low rate or 8 clients sharing 200 queries, so a run
+    fits the CPU."""
     root = tmp_path_factory.mktemp("tiny")
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     (root / "bench" / "configs").mkdir(parents=True)
@@ -35,22 +35,9 @@ def tiny_root(tmp_path_factory):
         if "rate_per_s" in mix:
             mix["rate_per_s"] = 40
         if "clients" in mix:
-            mix["clients"] = 8
+            mix["clients"], mix["set_size"] = 8, 200
         (root / "bench" / "traffic" / f"{w['traffic']}.json").write_text(
             json.dumps(mix))
-    # a closed-loop cell, added as files and entries only: its clients
-    # fill every micro-batch
-    closed = {"loop": "closed", "clients": 8, "set_size": 200,
-              "queries": {"set_seed": 7, "min_terms": 2, "max_terms": 10},
-              "warmup_s": 1}
-    (root / "bench" / "traffic" / "closed.json").write_text(
-        json.dumps(closed))
-    bench["workloads"].append({"name": "passage.closed",
-                               "config": "msmarco-passage-4x2",
-                               "traffic": "closed", "chips": 1, "why": "x"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "passage.steady" in m.get("workloads", ()):
-            m["workloads"].append("passage.closed")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 
@@ -58,8 +45,8 @@ def tiny_root(tmp_path_factory):
 @pytest.fixture
 def cpu_state(tiny_root, monkeypatch):
     """Snapshots, logs, traces and the compile cache under the tiny root."""
-    import deploy
     import device
-    monkeypatch.setattr(deploy, "STATE", tiny_root / "state")
+    import harness
+    monkeypatch.setattr(harness, "STATE", tiny_root / "state")
     monkeypatch.setattr(device, "CACHE_DIR", tiny_root / "jax_cache")
     return tiny_root

@@ -7,7 +7,6 @@ import shutil
 import pytest
 
 import cells
-import corpus
 import generate
 from conftest import BENCH, ROOT
 
@@ -29,6 +28,8 @@ def test_every_cell_loads_by_name(bench):
         assert cell.per_layer, w["name"]
         assert cell.config["name"] == w["config"]
         assert cell.mix["loop"] in ("open", "closed")
+        # the configuration's kind serves every request kind of the mix
+        generate.shares(cell.mix, cells.kind(cell.config["kind"], ROOT))
 
 
 def test_unknown_cell_is_refused():
@@ -120,8 +121,8 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path):
     assert cell.config["deployment"]["n_shards"] == 8
     assert cell.mix["rate_per_s"] == 10
     cfg = dict(cell.config, passages=500)
-    plan = generate.plan(cell.mix, cfg, corpus.make_corpus(cfg), 2**31 + 5,
-                         50.0)
+    plan = generate.plan(cell.mix, cells.kind(cfg["kind"], root), cfg,
+                         2**31 + 5, 50.0)
     assert len(plan.due) == 500
     assert 0.5 < ((plan.due % 5.0) < 1.0).mean() < 0.7
     assert [m["name"] for m in cell.per_layer] == ["constant.lat"]

@@ -49,7 +49,7 @@ def test_latency_counts_the_wait_behind_a_stall():
                               [None] * n, [[1]])
     server = SerialServer(0.001, 0.3)
     t0 = time.perf_counter() + 0.01
-    reads, late = drive.open_loop(server, sched, ["q"], t0, [])
+    reads, late = drive.open_loop(lambda q: server.submit("q"), sched, t0)
     lat = 1e3 * (reads.done - reads.due)
     assert np.all(~np.isnan(reads.done))
     # every request queued behind the 300 ms stall: timed from its due
@@ -74,7 +74,8 @@ def test_unanswered_requests_stay_nan():
     old = drive.GRACE_S
     drive.GRACE_S = 0.2
     try:
-        reads, _ = drive.open_loop(s, sched, ["q"], time.perf_counter(), [])
+        reads, _ = drive.open_loop(lambda q: s.submit("q"), sched,
+                                   time.perf_counter())
     finally:
         drive.GRACE_S = old
     assert np.isnan(reads.done).all()
@@ -83,7 +84,7 @@ def test_unanswered_requests_stay_nan():
 def test_closed_loop_keeps_every_client_busy():
     server = SerialServer(0.002, 0.0)
     plan = generate.ClosedPlan(4, np.arange(5), [[1]] * 5)
-    reads, _ = drive.closed_loop(server, plan, ["q"] * 5,
+    reads, _ = drive.closed_loop(lambda q: server.submit("q"), plan,
                                  time.perf_counter(), 0.3)
     done = reads.done[~np.isnan(reads.done)]
     assert len(done) > 50

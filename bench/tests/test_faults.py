@@ -15,7 +15,7 @@ def _run(root, cell, seed=11, **kw):
                        **kw)
 
 @pytest.mark.parametrize("cell", ["passage.steady", "acid.ycsb-b",
-                                  "passage.closed"])
+                                  "passage.saturate"])
 def test_sound_run_is_correct(cpu_state, cell):
     out = _run(cpu_state, cell)
     assert out["correct"], out["checks"]
@@ -23,7 +23,8 @@ def test_sound_run_is_correct(cpu_state, cell):
     assert list(out)[-1] == "checks"
     assert out["checks"]["score_gap"]["value"] < 1e-6
 
-def test_altered_answer_is_caught(cpu_state, monkeypatch):
+@pytest.mark.parametrize("cell", ["passage.steady", "passage.saturate"])
+def test_altered_answer_is_caught(cpu_state, monkeypatch, cell):
     from repro.train import serve
     real = serve.bm25_topk
 
@@ -37,7 +38,7 @@ def test_altered_answer_is_caught(cpu_state, monkeypatch):
         def lower(self, *a, **k):
             return real.lower(*a, **k)
     monkeypatch.setattr(serve, "bm25_topk", Altered())
-    out = _run(cpu_state, "passage.steady")
+    out = _run(cpu_state, cell)
     assert not out["correct"]
     assert out["checks"]["answers_wrong"]["value"] > 0
 
@@ -51,7 +52,7 @@ def test_half_the_batch_left_out_is_caught(cpu_state, monkeypatch):
         rows = real(self, keep)
         return [rows[i % len(rows)] for i in range(len(queries))]
     monkeypatch.setattr(RetrievalServer, "_handle", half)
-    out = _run(cpu_state, "passage.closed")
+    out = _run(cpu_state, "passage.saturate")
     assert not out["correct"]
     assert out["checks"]["answers_wrong"]["value"] > 0
 
@@ -82,7 +83,8 @@ def test_lost_log_is_caught(cpu_state, monkeypatch):
     assert not out["correct"]
     assert out["checks"]["durable_diff"]["value"] > 0
 
-@pytest.mark.parametrize("cell", ["passage.steady", "acid.ycsb-b"])
+@pytest.mark.parametrize("cell", ["passage.steady", "acid.ycsb-b",
+                                  "passage.saturate"])
 def test_bf16_control_is_not_correct(cpu_state, cell):
     out = _run(cpu_state, cell, hooks={"server": control.Bf16Server})
     assert not out["correct"]

@@ -2,9 +2,10 @@ import numpy as np
 import pytest
 
 import cells
-import corpus
 import generate
 from conftest import ROOT
+from kinds import passages
+from kinds.passages import corpus
 
 BIG_SEED = 2**31 + 12345
 
@@ -18,7 +19,7 @@ def _config_and_mix(cell):
 def small():
     cfg, _ = _config_and_mix("passage.steady")
     cfg = dict(cfg, passages=2000)
-    return cfg, corpus.make_corpus(cfg)
+    return cfg, passages.corpus_of(cfg)
 
 
 def test_corpus_is_the_configurations_own(small):
@@ -46,9 +47,9 @@ def test_vocabulary_grows_as_heaps_law_says():
 def test_same_seed_same_schedule_other_seed_same_work_other_order(small):
     cfg, c = small
     _, mix = _config_and_mix("acid.ycsb-b")
-    a = generate.open_schedule(mix, cfg, c, BIG_SEED, 10.0)
-    b = generate.open_schedule(mix, cfg, c, BIG_SEED, 10.0)
-    d = generate.open_schedule(mix, cfg, c, BIG_SEED + 1, 10.0)
+    a = generate.open_schedule(mix, passages, cfg, BIG_SEED, 10.0)
+    b = generate.open_schedule(mix, passages, cfg, BIG_SEED, 10.0)
+    d = generate.open_schedule(mix, passages, cfg, BIG_SEED + 1, 10.0)
     assert np.array_equal(a.due, b.due) and np.array_equal(a.query, b.query)
     assert [u.passage for u in a.updates if u] == \
         [u.passage for u in b.updates if u]
@@ -75,15 +76,15 @@ def test_same_seed_same_schedule_other_seed_same_work_other_order(small):
 def test_warm_up_stream_has_queries_of_its_own(small):
     cfg, c = small
     _, mix = _config_and_mix("passage.steady")
-    a = generate.open_schedule(mix, cfg, c, 7, 5.0, stream=0)
-    b = generate.open_schedule(mix, cfg, c, 7, 5.0, stream=1)
+    a = generate.open_schedule(mix, passages, cfg, 7, 5.0, stream=0)
+    b = generate.open_schedule(mix, passages, cfg, 7, 5.0, stream=1)
     assert a.queries != b.queries
 
 
 def test_known_item_queries_follow_ms_marco_and_are_mostly_unique(small):
     cfg, c = small
     _, mix = _config_and_mix("passage.steady")
-    qs = generate.queries(mix, c, 0, 3000)
+    qs = passages.queries(mix, c, 0, 3000)
     lens = np.array([len(q) for q in qs])
     assert lens.min() >= 1 and lens.max() <= 10
     assert 5.3 < lens.mean() < 6.3
@@ -100,7 +101,7 @@ def test_df_ceiling_keeps_only_rare_words(small):
     _, mix = _config_and_mix("passage.steady")
     rare = dict(mix, queries=dict(mix["queries"], max_df_share=0.01))
     df = corpus.document_frequency(c)
-    qs = generate.queries(rare, c, 0, 200, df)
+    qs = passages.queries(rare, c, 0, 200, df)
     assert len(qs) == 200
     assert all(df[t] <= 0.01 * c.n for q in qs for t in q)
     assert all(len(q) >= mix["queries"]["min_terms"] for q in qs)
@@ -112,7 +113,7 @@ def test_an_arrival_pattern_shapes_the_rate(small):
     cfg, c = small
     _, mix = _config_and_mix("passage.steady")
     burst = dict(mix, rate_per_s=100, pattern=[[1.0, 3.0], [4.0, 0.5]])
-    s = generate.open_schedule(burst, cfg, c, BIG_SEED, 20.0)
+    s = generate.open_schedule(burst, passages, cfg, BIG_SEED, 20.0)
     assert len(s.due) == 2000
     in_burst = (s.due % 5.0) < 1.0
     assert 0.5 < in_burst.mean() < 0.7           # 3 / (3 + 2) of requests
@@ -122,9 +123,9 @@ def test_closed_loop_plan_is_seeded_and_deals_every_query(small):
     cfg, c = small
     _, mix = _config_and_mix("passage.steady")
     closed = dict(mix, loop="closed", clients=4, set_size=40)
-    a = generate.closed_plan(closed, c, BIG_SEED)
-    b = generate.closed_plan(closed, c, BIG_SEED)
-    d = generate.closed_plan(closed, c, BIG_SEED + 1)
+    a = generate.closed_plan(closed, passages, cfg, BIG_SEED)
+    b = generate.closed_plan(closed, passages, cfg, BIG_SEED)
+    d = generate.closed_plan(closed, passages, cfg, BIG_SEED + 1)
     assert np.array_equal(a.order, b.order)
     assert not np.array_equal(a.order, d.order)
     assert sorted(np.concatenate([a.of(k) for k in range(4)])) == \
@@ -134,9 +135,9 @@ def test_closed_loop_plan_is_seeded_and_deals_every_query(small):
 def test_unknown_request_kind_is_refused(small):
     cfg, c = small
     _, mix = _config_and_mix("passage.steady")
-    with pytest.raises(ValueError):
-        generate.open_schedule(dict(mix, requests={"scan": 1.0}), cfg, c, 1,
-                               1.0)
+    with pytest.raises(ValueError, match=r"\['query', 'update'\]"):
+        generate.open_schedule(dict(mix, requests={"scan": 1.0}), passages,
+                               cfg, 1, 1.0)
 
 
 def test_words_are_their_own_distinct_porter_stems():

@@ -9,8 +9,9 @@ from conftest import ROOT
 
 
 def _ctx(**kw):
-    base = dict(batch={}, phase={}, timings=({"scatter_s": 0, "merge_s": 0},
-                                             {"scatter_s": 0, "merge_s": 0}))
+    base = dict(batch={}, phase={}, kernel="bm25_topk",
+                timings=({"scatter_s": 0, "merge_s": 0},
+                         {"scatter_s": 0, "merge_s": 0}))
     base.update(kw)
     return harness.Context(**base)
 

@@ -6,8 +6,7 @@ import math
 import numpy as np
 import pytest
 
-import corpus
-import reference
+from kinds.passages import corpus, reference
 
 
 def _docs():

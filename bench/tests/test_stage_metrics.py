@@ -12,7 +12,8 @@ def _phase(name):
 
 
 def _ctx(phase):
-    return harness.Context(batch={(): (4, 10.0)}, phase=phase)
+    return harness.Context(batch={(): (4, 10.0)}, phase=phase,
+                           kernel="bm25_topk")
 
 
 @pytest.mark.parametrize("metric, phase", [("impacts_ms.lat", "impacts"),
@@ -25,4 +26,5 @@ def test_phase_ms_per_batch(metric, phase):
 @pytest.mark.parametrize("metric", ["impacts_ms.lat", "dispatch_ms.lat"])
 def test_a_program_without_the_phase_reads_nothing(metric):
     assert cells.reader(metric)(_ctx({_phase("gather"): (16, 40.0)})) is None
-    assert cells.reader(metric)(harness.Context(batch={}, phase={})) is None
+    assert cells.reader(metric)(harness.Context(
+        batch={}, phase={}, kernel="bm25_topk")) is None
