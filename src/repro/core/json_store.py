@@ -11,14 +11,19 @@ tokens, and *in the features* via path annotations:
 
 Nothing is flattened: T(lo, hi) reproduces the full object.  A date
 annotator shows post-hoc annotation (paper Examples 8/9): it unifies
-heterogeneous date formats into year=/month=/day= features.
+heterogeneous date formats into year=/month=/day= features, and gives each
+date a day number that range predicates read.  ``extract_columns`` turns
+one snapshot's objects of a collection into row-aligned columns.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import datetime as _dt
 import re
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Mapping, Optional, Tuple
+
+import numpy as np
 
 from .featurizer import (STRUCT_COLON, STRUCT_COMMA, STRUCT_LBRACE,
                          STRUCT_LBRACKET, STRUCT_QUOTE, STRUCT_RBRACE,
@@ -34,13 +39,16 @@ _DISPLAY = {STRUCT_LBRACE: "{", STRUCT_RBRACE: "}", STRUCT_LBRACKET: "[",
 
 
 class _Emitter:
-    def __init__(self, tokenizer: Utf8Tokenizer):
+    """Content parts and their token positions (none counted without a
+    tokenizer: the text alone)."""
+
+    def __init__(self, tokenizer: Optional[Utf8Tokenizer]):
         self.tokenizer = tokenizer
         self.parts: List[str] = []
         self.pos = 0  # token count so far
 
     def emit(self, text: str) -> Tuple[int, int]:
-        n = len(self.tokenizer.tokenize(text))
+        n = len(self.tokenizer.tokenize(text)) if self.tokenizer else 0
         lo = self.pos
         self.pos += n
         self.parts.append(text)
@@ -60,14 +68,10 @@ def _scalar_repr(v: Any) -> Tuple[str, Optional[float]]:
     return str(v), None
 
 
-def add_json(w, obj: Any, collection: Optional[str] = None) -> Tuple[int, int]:
-    """Append a JSON object inside an open transaction on warren ``w``.
-
-    Returns the object's global or staging address extent.  ``collection``
-    adds a collection-membership feature over the object (the paper's
-    ``Files/books.json`` convention).
-    """
-    em = _Emitter(w.index.tokenizer)
+def _layout(obj: Any, tokenizer: Optional[Utf8Tokenizer]):
+    """(emitter, path annotations, root extent) of ``obj``: its content
+    and, with a tokenizer, each value's token interval in it."""
+    em = _Emitter(tokenizer)
     annotations: List[Tuple[str, int, int, float]] = []
 
     def _annotation_value(node: Any) -> float:
@@ -109,6 +113,23 @@ def add_json(w, obj: Any, collection: Optional[str] = None) -> Tuple[int, int]:
         return lo, hi
 
     rlo, rhi = walk(obj, ":")
+    return em, annotations, rlo, rhi
+
+
+def json_text(obj: Any) -> str:
+    """The content ``add_json`` appends for ``obj`` (what append routing
+    hashes)."""
+    return _layout(obj, None)[0].text()
+
+
+def add_json(w, obj: Any, collection: Optional[str] = None) -> Tuple[int, int]:
+    """Append a JSON object inside an open transaction on warren ``w``.
+
+    Returns the object's global or staging address extent.  ``collection``
+    adds a collection-membership feature over the object (the paper's
+    ``Files/books.json`` convention).
+    """
+    em, annotations, rlo, rhi = _layout(obj, w.index.tokenizer)
     glo, ghi = w.append(em.text())
     assert ghi - glo == em.pos - 1, "token accounting mismatch"
 
@@ -185,9 +206,26 @@ def parse_date(value: str) -> Optional[Tuple[int, int, int]]:
     return None
 
 
+_EPOCH = _dt.date(1970, 1, 1).toordinal()
+DAYS = "days"
+
+
+def day_number(y: int, m: int, d: int) -> int:
+    """Days since 1970-01-01."""
+    return _dt.date(y, m, d).toordinal() - _EPOCH
+
+
+def days_path(path: str) -> str:
+    """The feature whose annotations give the day number of each date under
+    ``path``; no key path ends this way, as every key path ends in ':'."""
+    return path + DAYS
+
+
 def annotate_dates(w, date_paths: Iterable[str]) -> int:
     """Read date-bearing fields via the index, write year=/month=/day=
-    annotations in the same transaction.  Returns #annotated fields."""
+    annotations and a ``days_path(path)`` annotation valued with the day
+    number over the same interval, in the same transaction.  Returns
+    #annotated fields."""
     count = 0
     for path in date_paths:
         lst = w.annotations(path)
@@ -204,5 +242,76 @@ def annotate_dates(w, date_paths: Iterable[str]) -> int:
             w.annotate(f"year={y}", int(p), int(q))
             w.annotate(f"month={mo:02d}", int(p), int(q))
             w.annotate(f"day={dy:02d}", int(p), int(q))
+            w.annotate(days_path(path), int(p), int(q),
+                       float(day_number(y, mo, dy)))
             count += 1
     return count
+
+
+# --------------------------------------------------------------------- #
+# Columns: one snapshot's objects of a collection, one row each.
+# --------------------------------------------------------------------- #
+INT32 = np.iinfo(np.int32)
+
+
+@dataclasses.dataclass
+class Columns:
+    """Row-aligned columns of one collection in one snapshot.  Row ``i`` is
+    the object at ``rows[i]`` (its extent; ascending addresses).  A numeric
+    path's ``values`` are exact fixed-point integers (the number times ten
+    to its scale), a coded path's are dense codes into ``codes[path]``
+    (its distinct strings, ascending); both are 0 where ``present`` is
+    False: the row lacks the path."""
+    rows: np.ndarray                       # int64 [N, 2]
+    values: Dict[str, np.ndarray]          # int64 [N]
+    present: Dict[str, np.ndarray]         # bool [N]
+    codes: Dict[str, Tuple[str, ...]]
+
+
+def _fixed_point(values: np.ndarray, scale: int, path: str) -> np.ndarray:
+    x = values * 10.0 ** scale
+    r = np.rint(x)
+    # beyond float64's rounding, a digit past the scale: not representable
+    if np.any(np.abs(x - r) > 1e-12 * np.maximum(np.abs(x), 1e3)):
+        raise ValueError(f"{path}: a value has more than {scale} decimals")
+    if len(r) and (r.min() < INT32.min or r.max() > INT32.max):
+        raise ValueError(f"{path}: a value does not fit 32 bits at scale "
+                         f"{scale}")
+    return r.astype(np.int64)
+
+
+def extract_columns(w, collection: str,
+                    paths: Mapping[str, Optional[int]]) -> Columns:
+    """Columns of ``collection``'s objects in the started warren ``w``.
+    ``paths`` maps each path to its scale, the decimals kept of a number
+    (day numbers: ``days_path``, scale 0), or to None for a string resolved
+    to a dense code from the content inside each value interval.  A value
+    belongs to the object whose extent contains it; erased objects are not
+    in the snapshot's lists, so they are not rows."""
+    roots = w.annotations(collection)
+    rs, re_ = roots.starts, roots.ends
+    n = len(rs)
+    out = Columns(np.stack([rs, re_], axis=1).reshape(n, 2), {}, {}, {})
+    for path, scale in paths.items():
+        lst = w.annotations(path)
+        row = np.searchsorted(rs, lst.starts, side="right") - 1
+        inside = row >= 0
+        inside[inside] = lst.ends[inside] <= re_[row[inside]]
+        row = row[inside]
+        if len(np.unique(row)) != len(row):
+            raise ValueError(f"{path} occurs twice in one {collection} "
+                             "object")
+        present = np.zeros(n, bool)
+        present[row] = True
+        values = np.zeros(n, np.int64)
+        if scale is None:
+            text = [raw_value_of(w, int(p), int(q)) for p, q in
+                    zip(lst.starts[inside], lst.ends[inside])]
+            uniq = tuple(sorted(set(text)))
+            code = {t: i for i, t in enumerate(uniq)}
+            values[row] = [code[t] for t in text]
+            out.codes[path] = uniq
+        else:
+            values[row] = _fixed_point(lst.values[inside], scale, path)
+        out.values[path], out.present[path] = values, present
+    return out

@@ -103,6 +103,12 @@ class Warren:
         self._require_started()
         return self._snapshot.tokens(p, q)
 
+    def max_seqnum(self) -> int:
+        """Largest commit seqnum in the started snapshot (-1 when empty):
+        with the index, it names the snapshot's committed state."""
+        self._require_started()
+        return max((s.seqnum for s in self._snapshot.segments), default=-1)
+
     def phrase(self, text: str) -> GCLNode:
         """Query helper: tokenize text, AND-adjacent tokens into a Phrase."""
         self._require_started()

@@ -764,13 +764,20 @@ class ShardedWarren:
         """Shut down the scatter pool (reads fall back to sequential)."""
         self.set_async_scatter(False)
 
-    def map_groups(self, fn) -> List:
+    def map_groups(self, fn, keyed: bool = False) -> List:
         """Apply ``fn(warren)`` to every group's serving replica, in group
         order of this session's pinned routing table, with per-group replica
         failover; fanned out on the scatter pool when async scatter is
         enabled, else a caller-thread loop.  If a rebalance swap lands
         mid-fan-out, the session refreshes its pinned view and retries —
-        readers are never aborted by a topology change."""
+        readers are never aborted by a topology change.
+
+        With ``keyed``, ``fn(warren, key)`` also gets the read's snapshot
+        key ``(group, epoch, seqnum)``: the group's rebalance epoch and the
+        highest commit seqnum of the snapshot read.  Replicas of a group
+        publish the same commits in order and compaction keeps the highest
+        seqnum, so equal keys name the same committed data; every commit,
+        erase and rebalance moves it."""
         self._require_started()
         for _ in range(8):
             table = self._table
@@ -778,17 +785,20 @@ class ShardedWarren:
             pool = self._ctx["scatter"]
             try:
                 if pool is not None and table.n_groups > 1:
-                    return pool.run([(lambda g=g: self._scatter_read(g, fn))
-                                     for g in gids])
-                return [self._scatter_read(g, fn) for g in gids]
+                    return pool.run([(lambda g=g: self._scatter_read(
+                        g, fn, keyed)) for g in gids])
+                return [self._scatter_read(g, fn, keyed) for g in gids]
             except _RouteEpochChanged:
                 self._refresh_view()
         raise ReplicaFailure("routing table kept changing mid-read")
 
-    def _scatter_read(self, group: int, fn):
+    def _scatter_read(self, group: int, fn, keyed: bool = False):
         """One group's leg of a fan-out: a ``scatter`` span plus the
         per-group latency histogram around the failover-protected read."""
         reg = obs.registry()
+        if keyed:
+            epoch = self._table.group_epochs[group]
+            fn = (lambda w, f=fn: f(w, (group, epoch, w.max_seqnum())))
         with obs.span("scatter", group=group):
             t0 = time.perf_counter()
             try:
@@ -891,8 +901,7 @@ class ShardedWarren:
                     group.mark_failed(r)
                     last = e
                     continue
-                seq = max((s.seqnum for s in w._snapshot.segments),
-                          default=-1)
+                seq = w.max_seqnum()
                 if seq >= floor:
                     self._hwm[gid] = (epoch, seq)
                     return (r, w)

@@ -11,6 +11,14 @@ batch in one flat ``[P]`` array, no padded rows; ``bm25_topk``'s compact
 form), per-group device ``bm25_topk`` dispatches overlap the next group's
 packing, and a global k-way merge yields exactly the single-index results.
 
+Beside BM25 text, the same batcher takes ``core.aggregate``'s
+``AggregateRequest``: exact filter-and-aggregate queries over a JSON
+collection (TPC-H Q1 and Q6 are two).  A batch splits by request type; the
+aggregate half fans out once per group, where each group's columns stay
+resident on the device for as long as its snapshot key holds, makes one
+device call per group for every request of the batch, and adds the
+groups' partial sums exactly on the host.
+
 LMServer wraps the transformer decode path with a KV cache and a simple
 continuous-batching slot scheduler.
 """
@@ -31,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import collection_stats, ranking, vectorized
+from repro.core import aggregate, collection_stats, ranking, vectorized
+from repro.core.aggregate import AggregateRequest
 from repro.core.vectorized import bm25_topk
 from repro.dist.parallel import ScatterTimings
 
@@ -204,6 +213,9 @@ class RetrievalServer:
         batcher = batcher or BatcherConfig()
         self._query_slots = self._pad_sizes(batcher.max_batch, 1, 1)[0]
         self._warm_widths: set = set()
+        # (group, collection) -> its resident columns; written only by the
+        # group's own leg of a fan-out
+        self._columns: Dict[Tuple[int, str], aggregate.ResidentColumns] = {}
         if self._sharded:
             self.stats = None    # the native path re-scatters per batch
         else:
@@ -230,18 +242,24 @@ class RetrievalServer:
     def query(self, text: str, timeout: float = 10.0):
         return self.batcher.submit(text).get(timeout=timeout)
 
-    def _handle(self, queries: List[str]) -> List[List[Tuple[int, float]]]:
-        # coalesce duplicate requests: a batch scores each distinct query
+    def _handle(self, queries: List[Any]) -> List[list]:
+        # coalesce duplicate requests: a batch serves each distinct request
         # once, every waiter gets (a copy of) the shared result row
         uniq = list(dict.fromkeys(queries))
-        rows = (self._handle_sharded(uniq) if self._sharded
-                else self._handle_single(uniq))
+        texts = [q for q in uniq if not isinstance(q, AggregateRequest)]
+        aggs = [q for q in uniq if isinstance(q, AggregateRequest)]
+        by_query = {}
+        if texts:
+            by_query.update(zip(texts, self._handle_sharded(texts)
+                                if self._sharded
+                                else self._handle_single(texts)))
+        if aggs:
+            by_query.update(zip(aggs, self._handle_aggregate(aggs)))
         if len(uniq) == len(queries):
-            return rows
+            return [by_query[q] for q in queries]
         # timings count served requests, so per-query figures stay
         # comparable with wall-clock ms/query over the same stream
         self.timings.add(queries=len(queries) - len(uniq))
-        by_query = dict(zip(uniq, rows))
         return [list(by_query[q]) for q in queries]
 
     def _query_terms(self, queries: List[str]) -> List[List[str]]:
@@ -547,6 +565,85 @@ class RetrievalServer:
         self.timings.add(scatter=t_scatter, score=t_score, merge=t_merge,
                          queries=qn)
         return out
+
+    # -- aggregate requests ------------------------------------------------ #
+    def _resident(self, w, key, collection: str,
+                  needed: set) -> aggregate.ResidentColumns:
+        """The group's resident columns of ``collection`` for snapshot
+        ``key``, holding at least ``needed``: rebuilt from the pinned
+        snapshot ``w`` and uploaded when the key moved or a column is new
+        (a miss), else the cached ones."""
+        group = key[0]
+        with obs.span("scatter.columns", group=group):
+            got = self._columns.get((group, collection))
+            if got is not None and got.key == key and needed <= set(got.specs):
+                return got
+            specs = sorted(needed | (set(got.specs) if got is not None
+                                     else set()),
+                           key=lambda c: (c.path, c.scale is None,
+                                          c.scale or 0))
+            got = aggregate.ResidentColumns(key, w, collection, specs)
+            self._columns[(group, collection)] = got
+            reg = obs.registry()
+            if reg.enabled:
+                reg.counter("serve_column_builds_total",
+                            "resident column tables built (a snapshot key "
+                            "moved or a column was new)", group=group).inc()
+            return got
+
+    def _handle_aggregate(self, requests: List[AggregateRequest]
+                          ) -> List[List[tuple]]:
+        by_coll: Dict[str, List[AggregateRequest]] = {}
+        for r in requests:
+            by_coll.setdefault(r.collection, []).append(r)
+        needed = {c: {col for r in rs for col in r.columns}
+                  for c, rs in by_coll.items()}
+
+        def read_group(w, key):
+            return {c: self._resident(w, key, c, needed[c]) for c in by_coll}
+
+        t0 = time.perf_counter()
+        with self.warren:
+            gathered = self.warren.map_groups(read_group, keyed=True)
+        t_scatter = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        reg = obs.registry()
+        if reg.enabled:
+            reg.gauge("serve_column_bytes",
+                      "bytes of every resident column table on the device"
+                      ).set(sum(t.nbytes for t in self._columns.values()))
+        qp = self._query_slots
+        launched = []
+        with obs.phase_timer("agg_scan", "dispatch"):
+            for coll, rs in by_coll.items():
+                tables = [g[coll] for g in gathered]
+                n_keys = aggregate.key_slots(tables, rs)
+                for t in tables:
+                    self._note_shapes("agg_scan", qp, *t.shape, n_keys)
+                    params = aggregate.encode(rs, t, qp)
+                    launched.append(aggregate.scan(t, params, n_keys))
+        with obs.phase_timer("agg_scan", "compute"):
+            results = aggregate.fetch(launched)
+        t_score = time.perf_counter() - t0
+        if reg.enabled:
+            reg.histogram("serve_agg_rows",
+                          "rows the device scans per micro-batch (every "
+                          "group's resident rows, once per collection)",
+                          lo=1.0, hi=1e10).observe(
+                sum(g[c].n_rows for g in gathered for c in by_coll))
+        t0 = time.perf_counter()
+        with obs.span("merge"):
+            out, at = {}, 0
+            for coll, rs in by_coll.items():
+                partials = [(g[coll], results[at + i])
+                            for i, g in enumerate(gathered)]
+                at += len(gathered)
+                for slot, r in enumerate(rs):
+                    out[r] = aggregate.finalize(r, partials, slot)
+        t_merge = time.perf_counter() - t0
+        self.timings.add(scatter=t_scatter, score=t_score, merge=t_merge,
+                         queries=len(requests))
+        return [out[r] for r in requests]
 
     def close(self):
         self.batcher.close()

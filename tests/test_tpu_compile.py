@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import aggregate
 from repro.core.vectorized import bm25_topk
 from repro.kernels import bm25_blockmax_topk
 
@@ -81,3 +82,21 @@ def test_bm25_blockmax_compiles_for_v5e(one_chip):
     compiled = fn.lower(_spec((nb, t, bs), jnp.float32, one_chip),
                         _spec((nb, t), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_rows", [1 << 15, 1 << 21])
+def test_agg_scan_compiles_for_v5e(one_chip, n_rows):
+    """The aggregate scan at a group's shape: 16 request slots over 8
+    resident columns of 2^15 rows (a group at TPC-H SF 0.02) and 2^21
+    (SF 1), into 8 group keys, with 64-bit sums under a scoped x64."""
+    c, q = aggregate.COLUMNS_STEP, 16
+    w = aggregate._width(c)
+    with jax.enable_x64(True):
+        compiled = aggregate.agg_scan.lower(
+            _spec((c, n_rows), jnp.int32, one_chip),
+            _spec((c, n_rows), jnp.bool_, one_chip),
+            _spec((q, w), jnp.int32, one_chip), n_keys=8).compile()
+    assert "s64" in compiled.as_text()
+    # the intermediates stay near a few [Q, sums, rows] int64 arrays
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        4 * q * aggregate.MAX_SUMS * n_rows * 8
