@@ -9,16 +9,19 @@ average of a product of affine terms of columns (``price * (1 - disc) *
 
 Columns are exact fixed-point integers (``json_store.extract_columns``), so
 every factor and product is an integer in the columns' units, and every
-sum is exact: the device accumulates in 64-bit integers under a scoped
-``jax.enable_x64`` (the process-wide flag stays off, so other kernels keep
-their dtypes), and a request whose sums could overflow them is refused.
+sum is exact: the device forms each product in 64-bit integers under a
+scoped ``jax.enable_x64`` (the process-wide flag stays off, so other
+kernels keep their dtypes), adds up its 8-bit limbs with an int8 matmul
+into int32 and recombines them in 64 bits, and a request whose sums could
+overflow 64 bits is refused.
 A row that lacks a column the request reads is not counted, as if a
 predicate on it had failed.
 
 Per group of a sharded warren, :class:`ResidentColumns` holds one
 collection's columns on the device for one snapshot key, and
 :func:`agg_scan` evaluates every request of a micro-batch in one call,
-returning per-request, per-group-key partial sums; :func:`finalize` adds
+forming each of the batch's distinct products once per row and returning
+per-request, per-group-key partial sums of all of them; :func:`finalize` adds
 the groups' partials on the host and forms averages and the SQL order
 (ascending group-by values).  Nothing but the columns outlives a batch.
 """
@@ -42,9 +45,12 @@ MAX_GROUP_BY = 4     # group-by columns per request
 MAX_SUMS = 8         # distinct products summed per request
 MAX_FACTORS = 3      # affine factors per product
 KEYS_FLOOR = 8       # group-key slots: a power of two, at least this
+PRODUCTS_FLOOR = 8   # a batch's product slots: a power of two, at least this
 ROWS_FLOOR = 1024    # row slots: a power of two, at least this
 COLUMNS_STEP = 8     # column slots: a multiple of this
 ROW_BLOCK = 8192     # rows the device scan takes at a time
+LIMBS = 8            # 8-bit limbs of a 64-bit product
+MAX_ROWS = 1 << 24   # rows a table may hold: 128 a row fills an int32
 I32 = json_store.INT32
 OPS = ("sum", "avg", "count")
 
@@ -74,6 +80,9 @@ class Factor:
     column: Column
     coef: int = 1
     offset: int = 0
+
+
+Product = Tuple[Factor, ...]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,7 +141,7 @@ class AggregateRequest:
         return tuple(dict.fromkeys(cols))
 
     @property
-    def products(self) -> Tuple[Tuple[Factor, ...], ...]:
+    def products(self) -> Tuple[Product, ...]:
         """The distinct products its sums and averages need."""
         return tuple(dict.fromkeys(o.factors for o in self.outputs
                                    if o.op != "count"))
@@ -193,17 +202,31 @@ def key_slots(tables: Sequence[ResidentColumns],
                  KEYS_FLOOR)
 
 
+def batch_products(requests: Sequence[AggregateRequest]
+                   ) -> Tuple[Product, ...]:
+    """The distinct products of every request's sums and averages, in
+    first-use order: the scan forms each once per row, whichever requests
+    share it."""
+    return tuple(dict.fromkeys(p for r in requests for p in r.products))
+
+
 def _width(c_pad: int) -> int:
-    return 1 + c_pad + 3 * MAX_RANGES + 2 * MAX_GROUP_BY \
-        + 3 * MAX_SUMS * MAX_FACTORS
+    return 1 + c_pad + 3 * MAX_RANGES + 2 * MAX_GROUP_BY
 
 
 def encode(requests: Sequence[AggregateRequest], table: ResidentColumns,
-           slots: int) -> np.ndarray:
-    """Every request's parameters as one int32 row of ``[slots, W]``:
-    the table's row count, the columns it reads, its ranges, its
-    group-key strides and its products' factors, each as column slot and
-    constants.  Raises OverflowError where a sum could leave 64 bits."""
+           slots: int, products: Sequence[Product]
+           ) -> Tuple[np.ndarray, np.ndarray]:
+    """The batch's parameters over one table: every request as one int32
+    row of ``[slots, W]`` (the table's row count, the columns it reads,
+    its ranges and its group-key strides, each as column slot and
+    constants), and ``products`` as int32 ``[P, MAX_FACTORS, 3]`` (each
+    factor's column slot, coef and offset; ``P`` bucketed to a power of
+    two from ``PRODUCTS_FLOOR``).  Raises OverflowError where a sum could
+    leave 64 bits or the table holds more than ``MAX_ROWS`` rows."""
+    if table.n_rows > MAX_ROWS:
+        raise OverflowError(f"{table.n_rows} rows: a limb sum could "
+                            f"leave 32 bits past {MAX_ROWS}")
     c_pad = table.shape[0]
     out = np.zeros((slots, _width(c_pad)), np.int64)
     for qi, r in enumerate(requests):
@@ -226,25 +249,24 @@ def encode(requests: Sequence[AggregateRequest], table: ResidentColumns,
         for j, c in enumerate(r.group_by):
             row[at + 2 * j:at + 2 * j + 2] = (table.index[c], stride)
             stride *= max(1, len(table.codes[c]))
-        at += 2 * MAX_GROUP_BY
-        for j, factors in enumerate(r.products):
-            bound = table.n_rows
-            for k, f in enumerate(factors):
-                i = table.index[f.column]
-                at_f = at + 3 * (j * MAX_FACTORS + k)
-                row[at_f:at_f + 3] = (i, f.coef, f.offset)
-                bound *= abs(f.offset) + abs(f.coef) * table.max_abs[i]
-            for k in range(len(factors), MAX_FACTORS):
-                at_f = at + 3 * (j * MAX_FACTORS + k)
-                row[at_f:at_f + 3] = (0, 0, 1)      # a factor of one
-            if bound >= 1 << 63:
-                raise OverflowError(f"a sum of {factors} could leave 64 bits")
-    return out.astype(np.int32)
+    fac = np.zeros((_pow2(len(products), PRODUCTS_FLOOR), MAX_FACTORS, 3),
+                   np.int64)
+    fac[..., 2] = 1                                 # factors of one
+    for j, prod in enumerate(products):
+        bound = table.n_rows
+        for k, f in enumerate(prod):
+            i = table.index[f.column]
+            fac[j, k] = (i, f.coef, f.offset)
+            bound *= abs(f.offset) + abs(f.coef) * table.max_abs[i]
+        if bound >= 1 << 63:
+            raise OverflowError(f"a sum of {prod} could leave 64 bits")
+    return out.astype(np.int32), fac.astype(np.int32)
 
 
-def _block(values, present, rows, params, n_keys: int):
-    """:func:`agg_scan` over one block of rows, numbered ``rows``."""
-    c = values.shape[0]
+def _block(values, present, rows, params, factors, n_keys: int):
+    """:func:`agg_scan`'s int32 limb sums over one block of rows, numbered
+    ``rows``: ``[Q * n_keys, P * LIMBS + 1]``."""
+    c, b = values.shape
     q = params.shape[0]
     need = params[:, 1:1 + c] > 0                               # [Q, C]
     ok = jnp.all(present[None] | ~need[:, :, None], axis=1)     # [Q, B]
@@ -255,47 +277,68 @@ def _block(values, present, rows, params, n_keys: int):
     ok &= jnp.all((v >= rng[..., 1:2]) & (v <= rng[..., 2:3]), axis=1)
     at += 3 * MAX_RANGES
     grp = params[:, at:at + 2 * MAX_GROUP_BY].reshape(q, MAX_GROUP_BY, 2)
-    key = jnp.sum(values[grp[..., 0]] * grp[..., 1:2], axis=1)  # [Q, B]
-    at += 2 * MAX_GROUP_BY
-    fac = params[:, at:].reshape(q, MAX_SUMS, MAX_FACTORS, 3)
-    terms = (fac[..., 1:2].astype(jnp.int64)
-             * values[fac[..., 0]].astype(jnp.int64)
-             + fac[..., 2:3].astype(jnp.int64))            # [Q, S, F, B]
-    prod = jnp.prod(terms, axis=2)                              # [Q, S, B]
-    hit = ok[:, None, :] & (key[:, None, :]
-                            == jnp.arange(n_keys)[None, :, None])  # [Q, G, B]
-    sums = jnp.sum(jnp.where(hit[:, :, None, :], prod[:, None], 0), axis=-1)
-    count = jnp.sum(hit, axis=-1, dtype=jnp.int64)[..., None]
-    return jnp.concatenate([sums, count], axis=-1)
+    key = jnp.sum(values[grp[..., 0]] * grp[..., 1:2], axis=1,
+                  dtype=jnp.int32)                              # [Q, B]
+    keys = jnp.arange(n_keys, dtype=jnp.int32)[None, :, None]
+    hit = ok[:, None, :] & (key[:, None, :] == keys)            # [Q, G, B]
+    terms = (factors[..., 1:2].astype(jnp.int64)
+             * values[factors[..., 0]].astype(jnp.int64)
+             + factors[..., 2:3].astype(jnp.int64))             # [P, F, B]
+    prod = jnp.prod(terms, axis=1)                              # [P, B]
+    # each product's two's complement bytes, less 128 to fit int8: a
+    # product is the sum of (limb + 128) * 256^i, modulo 2^64
+    words = jnp.stack([prod.astype(jnp.int32),
+                       (prod >> 32).astype(jnp.int32)], axis=1)  # [P, 2, B]
+    shifts = 8 * jnp.arange(LIMBS // 2, dtype=jnp.int32)[:, None]
+    limbs = ((words[:, :, None] >> shifts) & 255) - 128       # [P, 2, 4, B]
+    limbs = jnp.concatenate([limbs.reshape(-1, b).astype(jnp.int8),
+                             jnp.ones((1, b), jnp.int8)])       # + a count
+    return jax.lax.dot_general(
+        hit.reshape(q * n_keys, b).astype(jnp.int8), limbs,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_keys",))
-def agg_scan(values, present, params, n_keys: int):
+def agg_scan(values, present, params, factors, n_keys: int):
     """Every request's partial aggregates over one group's columns.
 
     ``values`` int32 and ``present`` bool ``[C, N]``; ``params`` int32
-    ``[Q, W]`` from :func:`encode`.  Returns int64 ``[Q, n_keys,
-    MAX_SUMS + 1]``: per request and group key, each product's sum over
-    the rows that pass, then their count.  Rows go in blocks of at most
-    ``ROW_BLOCK``, so the intermediates stay the size of one block's.
-    Call under ``jax.enable_x64(True)``."""
+    ``[Q, W]`` and ``factors`` int32 ``[P, MAX_FACTORS, 3]`` from
+    :func:`encode`.  Returns int64 ``[Q, n_keys, P + 1]``: per request and
+    group key, each product's sum over the rows that pass, then their
+    count.  Per block of at most ``ROW_BLOCK`` rows, the products are
+    formed in int64 and split into 8-bit limbs, and one int8 matmul of the
+    0/1 hit matrix by the limbs adds them up in int32 (exact, as a limb
+    sum is at most 128 a row and a table at most ``MAX_ROWS`` rows); the
+    limb sums are recombined in wrapping int64, exact as :func:`encode`
+    keeps every sum within 64 bits.  Call under ``jax.enable_x64(True)``."""
     c, n = values.shape
+    q, p = params.shape[0], factors.shape[0]
     blk = min(n, ROW_BLOCK)
-    blocks = (values.reshape(c, n // blk, blk).transpose(1, 0, 2),
-              present.reshape(c, n // blk, blk).transpose(1, 0, 2),
-              jnp.arange(n, dtype=jnp.int32).reshape(n // blk, blk))
 
-    def step(acc, block):
-        return acc + _block(*block, params, n_keys), None
+    def step(i, acc):
+        at = i * blk
+        return acc + _block(
+            jax.lax.dynamic_slice_in_dim(values, at, blk, axis=1),
+            jax.lax.dynamic_slice_in_dim(present, at, blk, axis=1),
+            at + jnp.arange(blk, dtype=jnp.int32), params, factors, n_keys)
 
-    acc = jnp.zeros((params.shape[0], n_keys, MAX_SUMS + 1), jnp.int64)
-    return jax.lax.scan(step, acc, blocks)[0]
+    acc = jax.lax.fori_loop(0, n // blk, step, jnp.zeros(
+        (q * n_keys, p * LIMBS + 1), jnp.int32))
+    count = acc[:, -1:].astype(jnp.int64)
+    limbs = acc[:, :-1].reshape(-1, p, LIMBS).astype(jnp.int64) \
+        + 128 * count[:, :, None]
+    sums = jnp.sum(limbs << (8 * jnp.arange(LIMBS, dtype=jnp.int64)),
+                   axis=-1)
+    return jnp.concatenate([sums, count], axis=1).reshape(q, n_keys, p + 1)
 
 
-def scan(table: ResidentColumns, params: np.ndarray, n_keys: int):
+def scan(table: ResidentColumns, params: np.ndarray, factors: np.ndarray,
+         n_keys: int):
     """Launch :func:`agg_scan` on ``table``; returns the device result."""
     with jax.enable_x64(True):
-        return agg_scan(table.values, table.present, params, n_keys=n_keys)
+        return agg_scan(table.values, table.present, params, factors,
+                        n_keys=n_keys)
 
 
 def fetch(results: list) -> List[np.ndarray]:
@@ -306,11 +349,12 @@ def fetch(results: list) -> List[np.ndarray]:
 
 def finalize(request: AggregateRequest,
              partials: Sequence[Tuple[ResidentColumns, np.ndarray]],
-             slot: int) -> List[tuple]:
+             slot: int, products: Sequence[Product]) -> List[tuple]:
     """The answer: the groups' partial sums of request ``slot`` (each
-    ``[n_keys, MAX_SUMS + 1]``) added exactly, keyed by group-by strings,
-    averages formed, rows in ascending group-by order."""
-    products = request.products
+    ``[n_keys, P + 1]``, over the batch's ``products``) added exactly,
+    keyed by group-by strings, averages formed, rows in ascending group-by
+    order."""
+    cols = [products.index(p) for p in request.products]
     acc: Dict[tuple, List[int]] = {}
     for table, part in partials:
         cards = [max(1, len(table.codes[c])) for c in request.group_by]
@@ -323,8 +367,8 @@ def finalize(request: AggregateRequest,
                 key.append(table.codes[c][rest % card])
                 rest //= card
             tot = acc.setdefault(tuple(key), [0] * (len(products) + 1))
-            for j in range(len(products)):
-                tot[j] += int(row[j])
+            for col in cols:
+                tot[col] += int(row[col])
             tot[-1] += int(row[-1])
     if not request.group_by and not acc:
         acc[()] = [0] * (len(products) + 1)

@@ -613,15 +613,19 @@ class RetrievalServer:
                       "bytes of every resident column table on the device"
                       ).set(sum(t.nbytes for t in self._columns.values()))
         qp = self._query_slots
-        launched = []
+        launched, products = [], {}
         with obs.phase_timer("agg_scan", "dispatch"):
             for coll, rs in by_coll.items():
                 tables = [g[coll] for g in gathered]
                 n_keys = aggregate.key_slots(tables, rs)
+                products[coll] = aggregate.batch_products(rs)
                 for t in tables:
-                    self._note_shapes("agg_scan", qp, *t.shape, n_keys)
-                    params = aggregate.encode(rs, t, qp)
-                    launched.append(aggregate.scan(t, params, n_keys))
+                    params, factors = aggregate.encode(rs, t, qp,
+                                                       products[coll])
+                    self._note_shapes("agg_scan", qp, *t.shape, n_keys,
+                                      len(factors))
+                    launched.append(aggregate.scan(t, params, factors,
+                                                   n_keys))
         with obs.phase_timer("agg_scan", "compute"):
             results = aggregate.fetch(launched)
         t_score = time.perf_counter() - t0
@@ -631,6 +635,11 @@ class RetrievalServer:
                           "group's resident rows, once per collection)",
                           lo=1.0, hi=1e10).observe(
                 sum(g[c].n_rows for g in gathered for c in by_coll))
+            reg.histogram("serve_agg_products",
+                          "distinct products the device scan forms per row "
+                          "per micro-batch (every collection's)",
+                          lo=1.0, hi=1e4).observe(
+                sum(len(p) for p in products.values()))
         t0 = time.perf_counter()
         with obs.span("merge"):
             out, at = {}, 0
@@ -639,7 +648,8 @@ class RetrievalServer:
                             for i, g in enumerate(gathered)]
                 at += len(gathered)
                 for slot, r in enumerate(rs):
-                    out[r] = aggregate.finalize(r, partials, slot)
+                    out[r] = aggregate.finalize(r, partials, slot,
+                                                products[coll])
         t_merge = time.perf_counter() - t0
         self.timings.add(scatter=t_scatter, score=t_score, merge=t_merge,
                          queries=len(requests))

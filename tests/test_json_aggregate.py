@@ -4,7 +4,10 @@ the plain reference exactly, before and after commits that insert and
 erase rows, mixed with BM25 queries in one batch."""
 
 import itertools
+from decimal import Decimal
+from fractions import Fraction
 
+import numpy as np
 import pytest
 
 import _tpch as T
@@ -194,3 +197,198 @@ def test_a_sum_that_could_leave_64_bits_is_refused(served):
         Output("s", "sum", (huge, huge, huge)),))
     with pytest.raises(OverflowError):
         _serve(server, [req])
+
+
+def test_a_mixed_batch_records_its_distinct_products(served):
+    _, server, _ = served
+    hist = lambda: next(m for _, m in obs.registry().series(  # noqa: E731
+        "serve_agg_products"))
+    server._handle([T.q1(90), T.q6(1994, 6, 24), T.q1(60)])
+    count, total = hist().count, hist().sum
+    server._handle([T.q1(90), T.q6(1994, 6, 24), T.q1(60)])
+    # Q1: qty, price, price(1-disc), price(1-disc)(1+tax), disc; Q6:
+    # price*disc; 128 a batch in a scan of MAX_SUMS a slot
+    assert (hist().count - count, hist().sum - total) == (1, 6)
+
+
+# -- the device scan against Python integers, on synthetic tables -------- #
+
+X = tuple(Column(f":x{i}:", 2 if i == 2 else 0) for i in range(3))
+K = (Column(":k0:", None), Column(":k1:", None))
+
+
+def _edge(n: int) -> int:
+    """The largest ``t`` with ``n * t**3`` under 2^63."""
+    t = round((2 ** 63 / n) ** (1 / 3))
+    while n * t ** 3 >= 2 ** 63:
+        t -= 1
+    while n * (t + 1) ** 3 < 2 ** 63:
+        t += 1
+    return t
+
+
+def _sum(name, *factors, op="sum"):
+    return Output(name, op, tuple(factors))
+
+
+def _req(*outputs, where=(), group_by=()):
+    return AggregateRequest("t", where=tuple(where),
+                            group_by=tuple(group_by), outputs=outputs)
+
+
+T63 = _edge(1000)
+F0, F1, F2 = (Factor(x) for x in X)
+NEG = Factor(X[0], -3, -7), Factor(X[1], 2, -100), Factor(X[2], -1, 0)
+SCAN_CASES = {
+    "negative_coefs_and_offsets": dict(n=3000, requests=[
+        _req(_sum("a", NEG[0], NEG[1]), _sum("b", NEG[2], op="avg"),
+             Output("n", "count"),
+             where=[aggregate.Range(X[0], -2000, 3000)]),
+        _req(_sum("c", *NEG), group_by=[K[0]])]),
+    "bound_just_under_2^63": dict(
+        n=1000, vals=((T63, T63), (-T63, -T63), (T63, T63)), requests=[
+            _req(_sum("pos", F0, F0, F2), _sum("neg", F0, F1, F2)),
+            _req(_sum("neg", F1, F1, F1), group_by=[K[1]])]),
+    "absent_values": dict(n=3000, absent=0.3, requests=[
+        _req(_sum("a", F0), Output("n", "count"), group_by=[K[0]]),
+        _req(_sum("b", F1, F2, op="avg"),
+             where=[aggregate.Range(X[2], None, 0)])]),
+    "padding_rows": dict(n=1000, requests=[
+        _req(Output("n", "count")),
+        _req(_sum("a", Factor(X[0], 3, 7), Factor(X[1], 0, -9)))]),
+    "several_row_blocks": dict(n=20000, requests=[
+        _req(_sum("a", F0, F1), Output("n", "count"),
+             where=[aggregate.Range(X[1], -500, None)], group_by=K),
+        _req(_sum("b", NEG[0], NEG[2]))]),
+    "several_group_keys": dict(n=3000, cards=(5, 7), requests=[
+        _req(_sum("a", F0), _sum("b", F2, op="avg"), group_by=K),
+        _req(Output("n", "count"), group_by=[K[1], K[0]])]),
+    "one_slot_used": dict(n=2000, requests=[
+        _req(_sum("a", F0, F1, F2), where=[aggregate.Range(X[0], 0, 0)])]),
+    "shared_and_own_products": dict(n=3000, requests=[
+        _req(_sum("a", F0, F1), _sum("b", F2)),
+        _req(_sum("b", F2), _sum("c", NEG[0]), group_by=[K[0]]),
+        _req(_sum("d", F1, NEG[1])),
+        _req(_sum("e", NEG[2], F0, F0))]),
+    "sixteen_product_slots": dict(n=2000, requests=[
+        _req(*(_sum(f"s{i}", Factor(X[i % 3], i - 4, 3 * i - 11))
+               for i in range(8))),
+        _req(*(_sum(f"t{i}", Factor(X[i % 3], i + 1), Factor(X[2], -1, i))
+               for i in range(4)))]),
+}
+
+
+def _columns(rng, n, vals, absent, cards):
+    """One group's synthetic columns: path -> (values, present, codes).
+    Each group codes its own subset of the strings, as a group would."""
+    cols = {}
+    for x, (lo, hi) in zip(X, vals):
+        cols[x.path] = (rng.integers(lo, hi, n, endpoint=True),
+                        rng.random(n) >= absent, None)
+    for k, card in zip(K, cards):
+        codes = tuple(f"{k.path}{i}" for i in
+                      sorted(rng.choice(card + 2, card, replace=False)))
+        cols[k.path] = (rng.integers(0, card, n),
+                        rng.random(n) >= absent, codes)
+    return cols
+
+
+def _extract(cols, collection, paths):
+    n = len(next(iter(cols.values()))[0])
+    out = json_store.Columns(np.zeros((n, 2), np.int64), {}, {}, {})
+    for p in paths:
+        v, present, codes = cols[p]
+        out.values[p], out.present[p] = np.where(present, v, 0), present
+        if codes is not None:
+            out.codes[p] = codes
+    return out
+
+
+def _python_answer(groups, request):
+    """``request`` over ``groups`` row by row in Python integers."""
+    products = request.products
+    acc = {}
+    for cols in groups:
+        for i in range(len(next(iter(cols.values()))[0])):
+            if not all(cols[c.path][1][i] for c in request.columns):
+                continue
+            if not all((r.lo is None or cols[r.column.path][0][i] >= r.lo)
+                       and (r.hi is None
+                            or cols[r.column.path][0][i] <= r.hi)
+                       for r in request.where):
+                continue
+            key = tuple(cols[c.path][2][cols[c.path][0][i]]
+                        for c in request.group_by)
+            tot = acc.setdefault(key, [0] * (len(products) + 1))
+            for j, p in enumerate(products):
+                v = 1
+                for f in p:
+                    v *= f.offset + f.coef * int(cols[f.column.path][0][i])
+                tot[j] += v
+            tot[-1] += 1
+    if not request.group_by and not acc:
+        acc[()] = [0] * (len(products) + 1)
+    rows = []
+    for key in sorted(acc):
+        tot = acc[key]
+        out = list(key)
+        for o in request.outputs:
+            if o.op == "count":
+                out.append(tot[-1])
+                continue
+            s = tot[products.index(o.factors)]
+            scale = sum(f.column.scale for f in o.factors)
+            out.append(None if tot[-1] == 0
+                       else Decimal(f"{s}E-{scale}") if o.op == "sum"
+                       else Fraction(s, 10 ** scale * tot[-1]))
+        rows.append(tuple(out))
+    return rows
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_the_scan_equals_python_integers(case, monkeypatch):
+    """Two groups' tables, scanned by :func:`aggregate.agg_scan` and
+    merged by :func:`aggregate.finalize`, equal the same requests over
+    the same rows in Python integers, and unused slots hold nothing."""
+    spec = {"vals": ((-5000, 5000),) * 3, "absent": 0.0, "cards": (3, 4),
+            **SCAN_CASES[case]}
+    rng = np.random.default_rng(sorted(SCAN_CASES).index(case) + 1017)
+    groups = [_columns(rng, spec["n"] - 7 * g, spec["vals"], spec["absent"],
+                       spec["cards"]) for g in range(2)]
+    monkeypatch.setattr(json_store, "extract_columns", _extract)
+    specs = sorted(set(X) | set(K), key=lambda c: c.path)
+    tables = [aggregate.ResidentColumns(g, cols, "t", specs)
+              for g, cols in enumerate(groups)]
+    requests, slots = spec["requests"], 16
+    products = aggregate.batch_products(requests)
+    n_keys = aggregate.key_slots(tables, requests)
+    parts = aggregate.fetch([aggregate.scan(
+        t, *aggregate.encode(requests, t, slots, products), n_keys)
+        for t in tables])
+    for part in parts:
+        assert part.shape == (slots, n_keys, 1 + max(
+            aggregate.PRODUCTS_FLOOR, 1 << (len(products) - 1).bit_length()))
+        assert not part[len(requests):].any()
+    for slot, r in enumerate(requests):
+        got = aggregate.finalize(r, list(zip(tables, parts)), slot, products)
+        assert got == _python_answer(groups, r)
+
+
+def test_encode_refuses_past_the_limb_rows_and_64_bits(monkeypatch):
+    """A table of more rows than an int32 limb sum can hold is refused,
+    whatever the request; so is a sum whose bound reaches 2^63, just."""
+    n = 1000
+    cols = _columns(np.random.default_rng(5), n, ((T63, T63 + 1),) * 3, 0.0,
+                    (3, 4))
+    monkeypatch.setattr(json_store, "extract_columns", _extract)
+    table = aggregate.ResidentColumns(0, cols, "t", sorted(
+        set(X) | set(K), key=lambda c: c.path))
+    over = [_req(_sum("s", F0, F1, F2))]
+    with pytest.raises(OverflowError, match="64 bits"):
+        aggregate.encode(over, table, 16, aggregate.batch_products(over))
+    count = [_req(Output("n", "count"))]
+    table.n_rows = aggregate.MAX_ROWS
+    aggregate.encode(count, table, 16, ())
+    table.n_rows = aggregate.MAX_ROWS + 1
+    with pytest.raises(OverflowError, match="32 bits"):
+        aggregate.encode(count, table, 16, ())

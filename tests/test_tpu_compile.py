@@ -8,6 +8,9 @@ one process may load the TPU library, and every test worker imports
 every test file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -88,15 +91,28 @@ def test_bm25_blockmax_compiles_for_v5e(one_chip):
 def test_agg_scan_compiles_for_v5e(one_chip, n_rows):
     """The aggregate scan at a group's shape: 16 request slots over 8
     resident columns of 2^15 rows (a group at TPC-H SF 0.02) and 2^21
-    (SF 1), into 8 group keys, with 64-bit sums under a scoped x64."""
-    c, q = aggregate.COLUMNS_STEP, 16
-    w = aggregate._width(c)
+    (SF 1), 8 products into 8 group keys: the sums are one int8 matmul
+    into int32 a block, and no int64 tensor is a block's size times the
+    slots and sums."""
+    c, q, p, g = aggregate.COLUMNS_STEP, 16, 8, 8
+    blk = aggregate.ROW_BLOCK
     with jax.enable_x64(True):
-        compiled = aggregate.agg_scan.lower(
+        lowered = aggregate.agg_scan.lower(
             _spec((c, n_rows), jnp.int32, one_chip),
             _spec((c, n_rows), jnp.bool_, one_chip),
-            _spec((q, w), jnp.int32, one_chip), n_keys=8).compile()
-    assert "s64" in compiled.as_text()
-    # the intermediates stay near a few [Q, sums, rows] int64 arrays
-    assert compiled.memory_analysis().temp_size_in_bytes < \
-        4 * q * aggregate.MAX_SUMS * n_rows * 8
+            _spec((q, aggregate._width(c)), jnp.int32, one_chip),
+            _spec((p, aggregate.MAX_FACTORS, 3), jnp.int32, one_chip),
+            n_keys=g)
+        compiled = lowered.compile()
+    text = lowered.as_text()
+    n = p * aggregate.LIMBS + 1
+    assert re.search(rf"dot_general .*\(tensor<{q * g}x{blk}xi8>, "
+                     rf"tensor<{n}x{blk}xi8>\) -> tensor<{q * g}x{n}xi32>",
+                     text)
+    assert re.search(r"= s32\[\S+ convolution\(", compiled.as_text())
+    for dims in re.findall(r"tensor<([0-9x]+)xi64>", text):
+        assert math.prod(map(int, dims.split("x"))) < \
+            q * aggregate.MAX_SUMS * blk
+    # nothing beyond one block's int8 hit matrix and limbs, at any size
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        (q * g + n) * blk
