@@ -22,11 +22,8 @@ from repro.data.synth import doc_generator
 
 def scatter_gather_bench(warren, queries, rounds: int = 25,
                          extra_docs: int = 0, smoke: bool = False):
-    """Same corpus, same query stream, three servings of a ShardedWarren:
+    """Same corpus, same query stream, two servings of a ShardedWarren:
 
-      legacy        the pre-async serving path: every term list is k-way
-                    merged across groups on the caller thread and scored in
-                    one global device block (ShardedWarren as "one index")
       native/seq    scatter once per group per micro-batch, per-group
                     device top-k, global merge — groups visited in a
                     sequential caller-thread loop
@@ -34,8 +31,9 @@ def scatter_gather_bench(warren, queries, rounds: int = 25,
                     ScatterGather worker pool
 
     Prints ms/query + the scatter/score/merge breakdown for each, verifies
-    all three return identical rankings, and reports the native/async
-    speedup over the legacy sequential scatter."""
+    both return ``score_bm25``'s top-10 over the same snapshot (the same
+    documents in the same order; scores to float32), and reports the
+    native/async speedup over the sequential scatter."""
     from repro.train.serve import BatcherConfig, RetrievalServer
 
     if extra_docs:                       # give each group real work
@@ -43,11 +41,10 @@ def scatter_gather_bench(warren, queries, rounds: int = 25,
         warren.index.merge_segments()    # serving cost, not merge state
     qs = queries * rounds
     results, times = {}, {}
-    for mode in ("legacy", "native/seq", "native/async"):
+    for mode in ("native/seq", "native/async"):
         warren.set_async_scatter(mode == "native/async")
         server = RetrievalServer(
-            warren, k=10, batcher=BatcherConfig(max_batch=16, max_wait_ms=4),
-            sharded_native=mode != "legacy")
+            warren, k=10, batcher=BatcherConfig(max_batch=16, max_wait_ms=4))
         for i in (1, 2, 4, 8, 16):               # warm every batch bucket
             server._handle(qs[:i])
         server.timings.reset()
@@ -58,23 +55,20 @@ def scatter_gather_bench(warren, queries, rounds: int = 25,
         print(f"  serving [{mode:>12}]: {1e3 * times[mode] / len(qs):7.2f} "
               f"ms/query wall — {server.timings.summary()}")
         server.close()
+    with warren:
+        ref = {q: score_bm25(warren, q, k=10) for q in queries}
     same = all(
-        [(d, round(s, 9)) for d, s in a] == [(d, round(s, 9)) for d, s in b]
-        for mode in ("native/seq", "native/async")
-        for a, b in zip(results["legacy"], results[mode]))
-    # the per-query search path must also agree between scatter modes
-    for enabled in (False, True):
-        warren.set_async_scatter(enabled)
-        with warren:
-            hits = [warren.search(q, k=10) for q in queries]
-        same = same and (hits == results.setdefault("_search", hits))
-    speedup = times["legacy"] / times["native/async"]
+        [d for d, _ in hits] == [d for d, _ in ref[q]]
+        and np.allclose([s for _, s in hits], [s for _, s in ref[q]],
+                        rtol=1e-6)
+        for res in results.values() for q, hits in zip(qs, res))
+    speedup = times["native/seq"] / times["native/async"]
     note = (" (smoke-sized corpus: parity check only, speedup needs the "
             "full run)" if smoke else "")
-    print(f"  all paths identical: {same}; native/async speedup over the "
-          f"legacy sequential scatter: {speedup:.2f}x{note}")
+    print(f"  both paths equal score_bm25: {same}; native/async speedup "
+          f"over the sequential scatter: {speedup:.2f}x{note}")
     if not same:
-        raise SystemExit("serving paths diverged on the same corpus")
+        raise SystemExit("serving paths diverged from score_bm25")
     return speedup
 
 
@@ -135,7 +129,7 @@ def rebalance_bench(shards: int = 3, replicas: int = 2,
             try:
                 t0 = time.time()
                 with wc:
-                    wc.search(q, k=10)
+                    score_bm25(wc, q, k=10)
                 with lock:
                     lat.append((t0, time.time() - t0))
             except Exception as e:        # noqa: BLE001 — zero reader aborts
@@ -209,7 +203,7 @@ def rebalance_bench(shards: int = 3, replicas: int = 2,
         n_1 = len(single.annotations(":"))
         ok = ok and n_s == n_1
         for q in queries:
-            got = sorted(round(s, 9) for _, s in warren.search(q, k=10))
+            got = sorted(round(s, 9) for _, s in score_bm25(warren, q, k=10))
             ref = sorted(round(s, 9) for _, s in score_bm25(single, q, k=10))
             ok = ok and got == ref
     print(f"  parity with single-index oracle over {n_s} docs: {ok}")

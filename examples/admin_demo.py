@@ -26,7 +26,7 @@ import threading
 import urllib.request
 
 from repro import obs
-from repro.core import ingest_documents
+from repro.core import ingest_documents, score_bm25
 from repro.data.synth import doc_generator
 from repro.dist.autopilot import (AutopilotConfig, ColdPolicy, Controller,
                                   HotSplitPolicy, Hysteresis)
@@ -104,7 +104,7 @@ def main() -> int:
     for _ in range(3):
         with warren:
             for q in QUERIES:
-                warren.search(q, k=10)
+                score_bm25(warren, q, k=10)
         ctl.tick()
         clock.advance()
     warren.demote_group(0)                  # /tiered/runs has content
@@ -115,7 +115,7 @@ def main() -> int:
     def load():
         while not stop.is_set():
             with warren:
-                warren.search(QUERIES[0], k=10)
+                score_bm25(warren, QUERIES[0], k=10)
 
     loader = threading.Thread(target=load, name="load", daemon=True)
     loader.start()

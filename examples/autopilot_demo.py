@@ -17,7 +17,7 @@ Run:  PYTHONPATH=src python examples/autopilot_demo.py
 
 import tempfile
 
-from repro.core import ingest_documents
+from repro.core import ingest_documents, score_bm25
 from repro.data.synth import doc_generator
 from repro.dist.autopilot import (AntiEntropyPolicy, AutopilotConfig,
                                   ColdPolicy, Controller, Hysteresis,
@@ -48,7 +48,7 @@ def main() -> None:
         if serve:
             with warren:
                 for q in QUERIES:
-                    warren.search(q, k=10)
+                    score_bm25(warren, q, k=10)
         for d in ctl.tick():
             print(f"  {d.summary()}")
         clock.advance()

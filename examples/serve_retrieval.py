@@ -154,11 +154,11 @@ def main():
     # replica failover: kill one replica of every group, answers unchanged
     if args.replicas > 1:
         with warren:
-            before = warren.search(queries[0], k=10)
+            before = score_bm25(warren, queries[0], k=10)
         for g in range(warren.n_shards):
             warren.mark_failed(g, g % args.replicas)
         with warren:
-            after = warren.search(queries[0], k=10)
+            after = score_bm25(warren, queries[0], k=10)
         same = [round(s, 9) for _, s in before] == \
                [round(s, 9) for _, s in after]
         print(f"failover (1 replica/group killed): scores identical={same}")
@@ -168,11 +168,11 @@ def main():
     # unchanged; the next write transparently promotes its group
     if args.demote_cold and args.shards > 1:
         with warren:
-            before = warren.search(queries[0], k=10)
+            before = score_bm25(warren, queries[0], k=10)
         for g in range(warren.n_shards):
             warren.demote_group(g)
         with warren:
-            after = warren.search(queries[0], k=10)
+            after = score_bm25(warren, queries[0], k=10)
         same = [round(s, 9) for _, s in before] == \
                [round(s, 9) for _, s in after]
         print(f"cold demotion ({warren.n_shards} groups -> static runs): "

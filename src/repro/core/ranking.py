@@ -148,7 +148,9 @@ def score_bm25(snapshot_or_warren, query: str, k: int = 10,
                k1: float = 0.9, b: float = 0.4,
                weights: Optional[Dict[str, float]] = None,
                stats: Optional[CollectionStats] = None) -> List[Tuple[int, float]]:
-    """Exhaustive BM25; returns [(doc_start_address, score)] best-first."""
+    """Exhaustive BM25; returns [(doc_start_address, score)] best-first:
+    the ``k`` best by (-score, address), so equal scores keep address
+    order, at the k-th score too."""
     stats = stats or collection_stats(snapshot_or_warren)
     if stats.n_docs == 0:
         return []
@@ -163,10 +165,13 @@ def score_bm25(snapshot_or_warren, query: str, k: int = 10,
         wq = 1.0 if weights is None else float(weights[t])
         di, imp = _impacts(lst, stats, idf, k1, b)
         np.add.at(acc, di, wq * imp)
-    k = min(k, stats.n_docs)
-    top = np.argpartition(-acc, k - 1)[:k]
-    top = top[np.argsort(-acc[top], kind="stable")]
-    return [(int(stats.doc_starts[i]), float(acc[i])) for i in top if acc[i] > 0]
+    top = np.flatnonzero(acc > 0)
+    if len(top) > k > 0:    # keep every document tied with the k-th score
+        kth = np.partition(acc[top], len(top) - k)[len(top) - k]
+        top = top[acc[top] >= kth]
+    # doc_starts ascend, so the document index orders ties by address
+    top = top[np.lexsort((top, -acc[top]))][:k]
+    return [(int(stats.doc_starts[i]), float(acc[i])) for i in top]
 
 
 # --------------------------------------------------------------------- #

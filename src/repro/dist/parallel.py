@@ -3,15 +3,15 @@
 Semantics.  :class:`ScatterGather` is a small worker pool that fans
 per-group read closures out concurrently and gathers results in input
 order.  It is the engine behind ``ShardedWarren``'s async scatter:
-``annotations``, ``global_stats``, ``search`` (both scatter phases) and
-``search_gcl`` hand it one closure per shard group instead of looping on
-the caller thread.  Each closure runs the group's full replica-failover
-protocol (``_group_read``) inside the worker, so a replica dying
-mid-scatter fails over exactly as it would on the sequential path —
-workers touch disjoint per-group state, which is what makes the fan-out
-safe.  The same ``map`` fan-out hosts a live shard migration's bulk
-segment streaming (``repro.dist.rebalance``), so rebalancing work runs on
-pool workers rather than a serving thread.
+``map_groups`` (which ``RetrievalServer`` serves every batch through),
+``annotations`` and ``search_gcl`` hand it one closure per shard group
+instead of looping on the caller thread.  Each closure runs the group's
+full replica-failover protocol (``_group_read``) inside the worker, so a
+replica dying mid-scatter fails over exactly as it would on the
+sequential path — workers touch disjoint per-group state, which is what
+makes the fan-out safe.  The same ``map`` fan-out hosts a live shard
+migration's bulk segment streaming (``repro.dist.rebalance``), so
+rebalancing work runs on pool workers rather than a serving thread.
 
 Failure model and invariants:
 
@@ -48,16 +48,15 @@ class ScatterTimings:
     """Thread-safe running sums of the serving-path time breakdown.
 
     ``scatter``  fan-out reads (per-group stats + annotation lists)
-    ``score``    host impacts, per-group packing + device/host scoring
+    ``score``    host impacts, per-group packing + device scoring
     ``merge``    the global k-way merge of per-group top-k lists
 
     The sums back ``snapshot`` (which callers difference over a window
     of their own), the human-readable ``summary``, and ``window()``,
-    which returns the sums since its last call and bumps ``epoch``: one
-    instance is shared across every clone of a warren (via ``_ctx``), so
-    long-lived servers report per-window rates instead of lifetime
-    averages.  Per-stage distributions come from the obs spans around
-    the same stages (``span_ms{span}``).
+    which returns the sums since its last call and bumps ``epoch``, so a
+    long-lived server (``RetrievalServer.timings``) reports per-window
+    rates instead of lifetime averages.  Per-stage distributions come
+    from the obs spans around the same stages (``span_ms{span}``).
     """
 
     def __init__(self):

@@ -47,20 +47,18 @@ Read path: the class exposes the exact Warren surface (start/end/
 transaction/annotations/hopper/translate/phrase/…) by k-way merging
 per-group annotation lists served from the *first live replica* of each
 group, with automatic failover to a sibling when a replica is marked failed
-(or raises :class:`ReplicaFailure`).  ``search`` is the scatter-gather fast
-path: global collection statistics are reduced first, each group scores its
-own documents with the *global* BM25 parameters, and a k-way merge yields
-the global top-k — identical scores to a single index even with R-1
-replicas of every group dead, before or after any number of rebalances.
+(or raises :class:`ReplicaFailure`).  ``map_groups`` hands a read to every
+group's serving replica at once: ``RetrievalServer`` ranks through it (one
+fan-out per micro-batch, global BM25 statistics, a k-way merge), so this
+module stores and routes but does not rank.
 
 Async scatter: with ``async_scatter=True`` (or ``set_async_scatter``) the
-per-group fan-outs of ``annotations``/``global_stats``/``search``/
-``search_gcl`` run on a shared :class:`~repro.dist.parallel.ScatterGather`
-worker pool instead of a sequential caller-thread loop; per-group replica
-failover runs unchanged inside each worker, results are merged in group
-order, and ``timings`` accumulates the scatter/score/merge breakdown.
-The pool, the timings, and the routing table are shared by every clone of
-the warren family.
+per-group fan-outs of ``map_groups``/``annotations``/``search_gcl`` run on
+a shared :class:`~repro.dist.parallel.ScatterGather` worker pool instead
+of a sequential caller-thread loop; per-group replica failover runs
+unchanged inside each worker and results are merged in group order.  The
+pool and the routing table are shared by every clone of the warren
+family.
 
 Failed replicas re-join via ``resurrect``: the lagging replica's state is
 rebuilt by streaming the durable segment form (``Segment.to_record``) from
@@ -79,19 +77,14 @@ and serves empty reads — so group ids stay dense and stable forever.
 from __future__ import annotations
 
 import bisect
-import heapq
-import itertools
 import os
 import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-import numpy as np
-
 from repro import obs
-from repro.core import ranking
-from repro.dist.parallel import ScatterGather, ScatterTimings
+from repro.dist.parallel import ScatterGather
 from repro.core.annotation import AnnotationList, merge_lists
 from repro.core.featurizer import Featurizer, JsonFeaturizer, murmur64a
 from repro.core.gcl import GCLNode, Phrase, Term
@@ -100,6 +93,7 @@ from repro.core.tokenizer import Tokenizer, Utf8Tokenizer
 from repro.core.warren import Warren
 
 STRIPE = 1 << 44          # address stripe per shard group (>> any index size)
+DOC_EXTENT = ":"          # the feature annotating each document's extent
 
 
 def shard_of(addr: int) -> int:
@@ -302,8 +296,6 @@ class ReplicaGroup:
         skew signal hot-split policies balance on.  Served from the first
         live replica (or the static run set when demoted); retired groups
         count zero."""
-        from repro.core.ranking import DOC_FEATURE
-
         if self.retired:
             return 0
         if self.demoted is not None:
@@ -312,13 +304,13 @@ class ReplicaGroup:
                 w = st.clone()
                 w.start()
                 try:
-                    return len(w.annotations(DOC_FEATURE))
+                    return len(w.annotations(DOC_EXTENT))
                 finally:
                     w.end()
         w = Warren(self.replicas[self.first_alive()])
         w.start()
         try:
-            return len(w.annotations(DOC_FEATURE))
+            return len(w.annotations(DOC_EXTENT))
         finally:
             w.end()
 
@@ -604,16 +596,14 @@ class ShardedWarren:
                     idx._next_addr = g * STRIPE
                     reps.append(idx)
                 self.groups.append(ReplicaGroup(g, reps))
-        # scatter pool + serving timings + the routing table, shared by
-        # every clone so a runtime toggle, a breakdown read, or a rebalance
-        # swap is seen by the whole family
+        # scatter pool + the routing table, shared by every clone so a
+        # runtime toggle or a rebalance swap is seen by the whole family
         if _shared is not None:
             self._ctx = _shared
         else:
             self._ctx = {
                 "scatter": (ScatterGather(scatter_workers)
                             if async_scatter else None),
-                "timings": ScatterTimings(),
                 "table": _table or RoutingTable.striped(len(self.groups)),
                 "rebalance_lock": obs.ProfiledLock("rebalance"),
             }
@@ -739,11 +729,6 @@ class ShardedWarren:
     @property
     def async_scatter(self) -> bool:
         return self._ctx["scatter"] is not None
-
-    @property
-    def timings(self) -> ScatterTimings:
-        """Scatter/score/merge breakdown of every ``search`` in the family."""
-        return self._ctx["timings"]
 
     @property
     def scatter_pool(self) -> Optional[ScatterGather]:
@@ -1201,92 +1186,6 @@ class ShardedWarren:
         return terms[0] if len(terms) == 1 else Phrase(terms)
 
     # -- scatter-gather serving ------------------------------------------- #
-    def global_stats(self) -> ranking.CollectionStats:
-        """Cross-group collection statistics (one pass, reduced).
-
-        Concatenated per-group vectors are re-sorted by document start
-        address: group order stops matching address order once a rebalance
-        has split or merged ranges, and downstream scoring binary-searches
-        ``doc_starts``."""
-        self._require_started()
-        per = self.map_groups(ranking.collection_stats)
-        n_docs = sum(s.n_docs for s in per)
-        total_len = sum(float(s.doc_lens.sum()) for s in per)
-        avgdl = total_len / n_docs if n_docs else 1.0
-        starts = np.concatenate([s.doc_starts for s in per])
-        ends = np.concatenate([s.doc_ends for s in per])
-        lens = np.concatenate([s.doc_lens for s in per])
-        if len(starts) and not np.all(starts[:-1] <= starts[1:]):
-            order = np.argsort(starts, kind="stable")
-            starts, ends, lens = starts[order], ends[order], lens[order]
-        return ranking.CollectionStats(n_docs, avgdl, starts, ends, lens)
-
-    def search(self, query: str, k: int = 10, k1: float = 0.9,
-               b: float = 0.4) -> List[Tuple[int, float]]:
-        """Scatter-gather BM25: per-group top-k + global k-way merge.
-
-        Global document frequencies and avgdl make per-group scores exactly
-        the single-index scores, so the merged top-k is exact — from any
-        live replica of each group, before or after any rebalance.
-        """
-        self._require_started()
-        t0 = time.perf_counter()
-        terms = list(dict.fromkeys(ranking.ranking_tokens(query)))
-        fvals = [ranking.TF_PREFIX + ranking.porter_stem(t) for t in terms]
-        # scatter 1: per-group stats + term lists (one replica per group)
-        gathered = self.map_groups(
-            lambda w: (ranking.collection_stats(w),
-                       [w.annotations(f) for f in fvals]))
-        per = [s for s, _ in gathered]
-        lists = [l for _, l in gathered]
-        n_groups = len(gathered)
-        n_docs = sum(s.n_docs for s in per)
-        if n_docs == 0:
-            self.timings.add(scatter=time.perf_counter() - t0)
-            return []
-        total_len = sum(float(s.doc_lens.sum()) for s in per)
-        avgdl = total_len / n_docs
-        # reduce document frequencies
-        dfs = [sum(len(lists[gi][ti]) for gi in range(n_groups))
-               for ti in range(len(terms))]
-        t1 = time.perf_counter()
-
-        # scatter 2: score each group with the GLOBAL idf/avgdl
-        def score_group(gi: int) -> List[Tuple[float, int]]:
-            stats = per[gi]
-            if stats.n_docs == 0:
-                return []
-            acc = np.zeros(stats.n_docs)
-            for ti in range(len(terms)):
-                lst = lists[gi][ti]
-                if len(lst) == 0 or dfs[ti] == 0:
-                    continue
-                idf = ranking._bm25_idf(n_docs, dfs[ti])
-                di, imp = ranking._impacts_with_avgdl(lst, stats, idf,
-                                                      avgdl, k1, b)
-                np.add.at(acc, di, imp)
-            kk = min(k, stats.n_docs)
-            top = np.argpartition(-acc, kk - 1)[:kk]
-            # order ties by doc index (= ascending address), so every run
-            # is sorted by the merge key below
-            top = top[np.lexsort((top, -acc[top]))]
-            return [(float(acc[i]), int(stats.doc_starts[i]))
-                    for i in top if acc[i] > 0]
-
-        pool = self._ctx["scatter"]
-        if pool is not None and n_groups > 1:
-            per_group_topk = pool.map(score_group, range(n_groups))
-        else:
-            per_group_topk = [score_group(g) for g in range(n_groups)]
-        t2 = time.perf_counter()
-        # gather: lazy k-way merge of per-group results; ties at equal
-        # scores resolve by address, matching the single-index argsort
-        merged = heapq.merge(*per_group_topk, key=lambda t: (-t[0], t[1]))
-        out = [(d, s) for s, d in itertools.islice(merged, k)]
-        t3 = time.perf_counter()
-        self.timings.add(scatter=t1 - t0, score=t2 - t1, merge=t3 - t2)
-        return out
-
     def search_gcl(self, query_text: str, limit: int = 1000) -> List:
         """Scatter-gather structural query: solve per group, concatenate.
 

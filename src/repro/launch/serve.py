@@ -50,8 +50,7 @@ def serve_retrieval(args):
     dt = time.time() - t0
     print(f"served {len(queries)} queries in {dt:.2f}s "
           f"({1e3 * dt / len(queries):.2f} ms/query, micro-batched)")
-    if args.shards > 1:
-        print(f"sharded serving breakdown: {server.timing_summary()}")
+    print(f"serving breakdown: {server.timing_summary()}")
     print(f"top-3 for {queries[0]!r}: {results[0][:3]}")
     server.close()
     if args.shards > 1:

@@ -1,15 +1,14 @@
 """Serving loops: dynamic request batching + the two first-stage retrievers.
 
 RetrievalServer serves ranked retrieval straight from an annotative index
-(the paper's workload): queries are micro-batched, impacts are laid out in
-the block-impact format, and scoring runs through either the exhaustive
-device path or the Block-Max Pallas kernel.  Over a ``ShardedWarren`` it
-serves *natively*: each micro-batch fans out once per shard group (on the
-warren's scatter pool when async scatter is enabled), every group packs its
-own compact block with GLOBAL collection statistics (every posting of the
-batch in one flat ``[P]`` array, no padded rows; ``bm25_topk``'s compact
-form), per-group device ``bm25_topk`` dispatches overlap the next group's
-packing, and a global k-way merge yields exactly the single-index results.
+(the paper's workload) through one path: queries are micro-batched, each
+batch fans out once per shard group (on the warren's scatter pool when
+async scatter is enabled; a warren with no groups is one group), every
+group packs its own compact block with GLOBAL collection statistics read
+in that same fan-out (every posting of the batch in one flat ``[P]``
+array, no padded rows; ``bm25_topk``'s compact form), per-group device
+``bm25_topk`` dispatches overlap the next group's packing, and a global
+k-way merge yields exactly the single-index results.
 
 Beside BM25 text, the same batcher takes ``core.aggregate``'s
 ``AggregateRequest``: exact filter-and-aggregate queries over a JSON
@@ -39,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import aggregate, collection_stats, ranking, vectorized
+from repro.core import aggregate, ranking, vectorized
 from repro.core.aggregate import AggregateRequest
 from repro.core.vectorized import bm25_topk
 from repro.dist.parallel import ScatterTimings
@@ -187,22 +186,21 @@ class RetrievalServer:
     ``Warren``, a ``ShardedWarren`` (with demoted cold groups), or a
     ``TieredWarren``, whose ``annotations`` already k-way merge the hot
     memtable with every on-disk static run, so scoring sees one logical
-    hot+cold list per term.  After commits, tier freezes, or shard
-    demotions change the collection, call :meth:`refresh_stats`.
+    hot+cold list per term.  Every batch reads its collection statistics
+    from the snapshot it scores, so commits, tier freezes and shard
+    demotions show in the next batch.
 
-    A ``ShardedWarren`` is served natively (scatter once per group, score
-    per group, merge globally); ``timings`` holds the per-batch
-    scatter/score/merge breakdown.
+    A ``ShardedWarren`` is served per group (scatter once per group, score
+    per group, merge globally); any other warren is served as one group.
+    ``timings`` holds the per-batch scatter/score/merge breakdown.
     """
 
     def __init__(self, warren, k: int = 10, batcher: BatcherConfig = None,
-                 max_terms: int = 8, max_postings: int = 4096,
-                 sharded_native: bool = True):
+                 max_terms: int = 8, max_postings: int = 4096):
         self.warren = warren
         self.k = k
         self.max_terms = max_terms
         self.max_postings = max_postings
-        self._sharded = sharded_native and hasattr(warren, "map_groups")
         self.timings = ScatterTimings()
         # device shapes already scored: a new shape tuple means the jitted
         # scorer compiles again — the counter Autopilot watches
@@ -216,25 +214,7 @@ class RetrievalServer:
         # (group, collection) -> its resident columns; written only by the
         # group's own leg of a fan-out
         self._columns: Dict[Tuple[int, str], aggregate.ResidentColumns] = {}
-        if self._sharded:
-            self.stats = None    # the native path re-scatters per batch
-        else:
-            with warren:
-                self.stats = collection_stats(warren)
         self.batcher = MicroBatcher(self._handle, batcher)
-
-    def refresh_stats(self) -> None:
-        """Re-derive collection statistics from a fresh snapshot; queries
-        already in flight finish against the stats they started with.
-        Reads through a clone so it never collides with the batcher
-        thread's start()/end() bracket on the serving warren.  The native
-        sharded path scatters fresh stats every batch, so there is
-        nothing to refresh."""
-        if self._sharded:
-            return
-        w = self.warren.clone()
-        with w:
-            self.stats = collection_stats(w)
 
     def timing_summary(self) -> str:
         return self.timings.summary()
@@ -250,9 +230,7 @@ class RetrievalServer:
         aggs = [q for q in uniq if isinstance(q, AggregateRequest)]
         by_query = {}
         if texts:
-            by_query.update(zip(texts, self._handle_sharded(texts)
-                                if self._sharded
-                                else self._handle_single(texts)))
+            by_query.update(zip(texts, self._handle_sharded(texts)))
         if aggs:
             by_query.update(zip(aggs, self._handle_aggregate(aggs)))
         if len(uniq) == len(queries):
@@ -266,16 +244,14 @@ class RetrievalServer:
         return [list(dict.fromkeys(ranking.ranking_tokens(q)))[:self.max_terms]
                 for q in queries]
 
-    @staticmethod
-    def _cap_by_impact(di: np.ndarray, imp: np.ndarray,
-                       limit: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Keep the top-``limit`` postings by impact (stable, so equal
-        impacts keep address order) — truncating by document order would
-        silently drop high-impact documents past the cap."""
-        if len(di) <= limit:
-            return di, imp
-        keep = np.argsort(-imp, kind="stable")[:limit]
-        return di[keep], imp[keep]
+    def _map_groups(self, fn, keyed: bool = False) -> list:
+        """``fn`` over every group of the started warren: the warren's own
+        fan-out when it has groups, else the warren as group 0 (keyed
+        ``(0, 0, max_seqnum)``, the shape of a sharded read's key)."""
+        w = self.warren
+        if hasattr(w, "map_groups"):
+            return w.map_groups(fn, keyed=keyed)
+        return [fn(w, (0, 0, w.max_seqnum())) if keyed else fn(w)]
 
     def _pad_sizes(self, qn: int, nterms: int,
                    longest: int) -> Tuple[int, int, int]:
@@ -283,7 +259,10 @@ class RetrievalServer:
         powers of two and the postings dim to a multiple of 256, so the
         jitted ``bm25_topk`` compiles a bounded set of shapes instead of
         one per (batch size, term count, longest list) — and short queries
-        don't pay for ``max_terms`` worth of padded scatter work."""
+        don't pay for ``max_terms`` worth of padded scatter work.  The
+        server reads only the batch dim; the term and posting dims size
+        ``bm25_topk``'s postings form, which only the passages benchmark's
+        warm-up compiles."""
         qp = max(1 << max(qn - 1, 0).bit_length(), 1)
         tp = min(self.max_terms, max(1 << max(nterms - 1, 0).bit_length(), 1))
         l = max(256, -(-longest // 256) * 256)
@@ -298,8 +277,8 @@ class RetrievalServer:
 
     def _note_shapes(self, *shape: int) -> None:
         """Count first sightings of a device shape bucket — each one is a
-        fresh XLA compile of the jitted scorer.  ``shape`` is (qp, tp, l,
-        nb) for the postings form, (qp, p, nb) for the compact form."""
+        fresh XLA compile of the jitted scorer.  ``shape`` is (qp, p, nb)
+        for the compact form, ("agg_scan", ...) for the aggregate scan."""
         key = (*shape, self.k)
         if key not in self._seen_shapes:
             self._seen_shapes.add(key)
@@ -334,63 +313,7 @@ class RetrievalServer:
         for p in sizes:
             self._note_shapes(qp, p, nb)
 
-    # -- single-index path ------------------------------------------------- #
-    def _handle_single(self, queries: List[str]
-                       ) -> List[List[Tuple[int, float]]]:
-        stats = self.stats      # one coherent stats version per batch
-        qn, l_cap = len(queries), self.max_postings
-        if stats.n_docs == 0:
-            return [[] for _ in queries]
-        t0 = time.perf_counter()
-        entries: List[Tuple[int, int, np.ndarray, np.ndarray]] = []
-        with self.warren:
-            for qi, terms in enumerate(self._query_terms(queries)):
-                for ti, term in enumerate(terms):
-                    lst = self.warren.annotations(
-                        ranking.TF_PREFIX + ranking.porter_stem(term))
-                    if not len(lst):
-                        continue
-                    idf = ranking._bm25_idf(stats.n_docs, len(lst))
-                    di, imp = ranking._impacts(lst, stats, idf,
-                                               k1=0.9, b=0.4)
-                    di, imp = self._cap_by_impact(di, imp, l_cap)
-                    entries.append((qi, ti, di, imp))
-        t_scatter = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        qp, tp, l = self._pad_sizes(
-            qn, max((e[1] + 1 for e in entries), default=1),
-            max((len(e[2]) for e in entries), default=1))
-        nb = self._acc_pad(stats.n_docs)
-        self._note_shapes(qp, tp, l, nb)
-        with obs.span("device_score"):
-            with obs.phase_timer("bm25_topk", "gather"):
-                doc_idx = np.full((qp, tp, l), nb, np.int32)
-                impacts = np.zeros((qp, tp, l), np.float32)
-                qmask = np.zeros((qp, tp), np.float32)
-                for qi, ti, di, imp in entries:
-                    doc_idx[qi, ti, :len(di)] = di
-                    impacts[qi, ti, :len(di)] = imp
-                    qmask[qi, ti] = 1.0
-            with obs.phase_timer("bm25_topk", "compute"):
-                scores, ids = bm25_topk(jnp.asarray(doc_idx),
-                                        jnp.asarray(impacts),
-                                        jnp.asarray(qmask),
-                                        n_docs=nb, k=self.k)
-                scores, ids = np.asarray(scores), np.asarray(ids)
-        t_score = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        with obs.span("merge"):
-            out = []
-            for qi in range(qn):
-                res = [(int(stats.doc_starts[d]), float(s))
-                       for d, s in zip(ids[qi], scores[qi]) if s > 0]
-                out.append(res)
-        t_merge = time.perf_counter() - t0
-        self.timings.add(scatter=t_scatter, score=t_score, merge=t_merge,
-                         queries=qn)
-        return out
-
-    # -- native ShardedWarren path ----------------------------------------- #
+    # -- text: BM25 per group ---------------------------------------------- #
     def _global_impacts(self, stems: List[str], per: list, lists: list,
                         n_docs: int) -> dict:
         """Per stem, every group's (doc_idx, impact) arrays under GLOBAL
@@ -401,7 +324,7 @@ class RetrievalServer:
         avgdl = float(np.concatenate([s.doc_lens for s in per]).mean())
         # per stem: per-group (doc_idx, impact) with GLOBAL df/avgdl, then
         # the posting cap applied to the *global* list so the kept postings
-        # are exactly the single-index path's
+        # are exactly those of the same collection served as one group
         term_group: Dict[str, Optional[List[Tuple[np.ndarray, np.ndarray]]]] \
             = {}
         empty = (np.zeros(0, np.int64), np.zeros(0))
@@ -452,7 +375,7 @@ class RetrievalServer:
 
         t0 = time.perf_counter()
         with self.warren:
-            gathered = self.warren.map_groups(read_group)
+            gathered = self._map_groups(read_group)
         t_scatter = time.perf_counter() - t0
         t0 = time.perf_counter()
         n_groups = len(gathered)
@@ -604,7 +527,7 @@ class RetrievalServer:
 
         t0 = time.perf_counter()
         with self.warren:
-            gathered = self.warren.map_groups(read_group, keyed=True)
+            gathered = self._map_groups(read_group, keyed=True)
         t_scatter = time.perf_counter() - t0
         t0 = time.perf_counter()
         reg = obs.registry()

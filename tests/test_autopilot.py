@@ -34,6 +34,7 @@ from hypothesis import given, settings, strategies as st
 
 from _sim import (RecordingActuator, decision_seq, run_scripted, sig,
                   tight_config)
+from repro.core import score_bm25
 from repro.dist.autopilot import (AntiEntropyPolicy, AutopilotConfig,
                                   ColdPolicy, Controller, Decision,
                                   GroupSignal, Hysteresis, HotSplitPolicy,
@@ -458,7 +459,7 @@ def test_closed_loop_on_real_warren_split_resync_demote(tmp_path):
     def serve():
         with sharded:
             for q in QUERIES:
-                sharded.search(q, k=10)
+                score_bm25(sharded, q, k=10)
 
     # phase 1 — traffic makes every group "hot" (p95 threshold 0); after
     # sustain_ticks the controller splits the largest group, then
@@ -515,7 +516,7 @@ def test_warren_signals_are_windowed(tmp_path):
     src.collect()                            # baseline snapshot
     with sharded:
         for q in QUERIES:
-            sharded.search(q, k=5)
+            score_bm25(sharded, q, k=5)
         total = len(sharded.annotations(":"))   # counts as reads too
     sigs = {s.group: s for s in src.collect()}
     assert sum(s.reads for s in sigs.values()) >= len(QUERIES)
@@ -574,7 +575,7 @@ def test_chaos_kill_replicas_mid_controller_split_backoff_reconverge():
         from test_rebalance import QUERIES
         with sharded:
             for q in QUERIES:
-                sharded.search(q, k=10)
+                score_bm25(sharded, q, k=10)
 
     # tick 0: the controller's split hits the kill — aborted, no torn table
     serve()

@@ -215,7 +215,7 @@ def test_quorum_chaos_no_torn_commits():
     with sw, single:
         for q in ("school education", "money business", "wind state"):
             ref = score_bm25(single, q, k=10)
-            got = sw.search(q, k=10)
+            got = score_bm25(sw, q, k=10)
             np.testing.assert_allclose(sorted(s for _, s in got),
                                        sorted(s for _, s in ref), rtol=1e-9)
 

@@ -344,6 +344,27 @@ def test_bm25_sanity():
         assert "fox" in best
 
 
+
+def test_bm25_ties_at_k_keep_lowest_addresses():
+    w = make_warren()
+    with w:
+        w.transaction()
+        for i in range(6):                      # six exact ties on "fox"
+            index_document(w, f"fox filler{i} pad", docid=str(i))
+        index_document(w, "fox fox pad", docid="best")
+        w.commit()
+    with w:
+        stats = collection_stats(w)
+        best = int(w.annotations("docid:best").starts[0])
+        top = score_bm25(w, "fox", k=3, stats=stats)
+        # the k boundary falls inside the tie: its lowest addresses stay
+        assert [d for d, _ in top] == [best, int(stats.doc_starts[0]),
+                                       int(stats.doc_starts[1])]
+        assert top[1][1] == top[2][1] < top[0][1]
+        full = score_bm25(w, "fox", k=7, stats=stats)
+        for k in range(1, 8):                   # every k: a prefix
+            assert score_bm25(w, "fox", k=k, stats=stats) == full[:k]
+
 def test_wand_and_blockmax_match_exhaustive():
     w = ranked_index()
     with w:

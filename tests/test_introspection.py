@@ -20,6 +20,7 @@ import urllib.request
 import pytest
 
 from repro import obs
+from repro.core import score_bm25
 from repro.obs import (SLO, AdminServer, MetricsRegistry, ProfiledLock,
                        RotatingJsonl, SamplingProfiler, SLOMonitor,
                        SLOSignalSource)
@@ -333,7 +334,7 @@ def test_admin_endpoints_and_scrapes_mid_rebalance(tmp_path):
         sharded, config=AutopilotConfig(pool=None), clock=clock)
     mon = SLOMonitor()
     with sharded:
-        sharded.search(QUERIES[0], k=5)     # seed a trace + latency metrics
+        score_bm25(sharded, QUERIES[0], k=5)  # seed a trace + latency metrics
     ctl.tick()
     mon.tick()
 

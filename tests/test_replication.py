@@ -5,14 +5,14 @@ erase / commit / abort sequences into a ``ShardedWarren(n_shards=3,
 replicas=2)`` and a single-index ``Warren`` and requires identical logical
 state: for every feature touched, the same annotation multiset (values +
 the text each interval annotates — addresses differ by design, stripes vs.
-sequential), and the same ``search()`` top-10.
+sequential), and the same ``score_bm25`` top-10.
 """
 
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import DynamicIndex, Warren, index_document
+from repro.core import DynamicIndex, Warren, index_document, score_bm25
 from repro.dist.checkpoint import CheckpointManager
 from repro.dist.elastic import repartition_replica_groups
 from repro.dist.shard_router import (QuorumError, ReplicaFailure,
@@ -111,8 +111,7 @@ def _annotation_view(warren, feature):
 
 def _search_view(warren, query, k=10):
     """(rounded score, text) pairs, ties grouped as frozensets."""
-    hits = warren.search(query, k=k) if isinstance(warren, ShardedWarren) \
-        else _single_search(warren, query, k)
+    hits = score_bm25(warren, query, k=k)
     docs = warren.annotations(":")
     ends = {int(s): int(e) for s, e in zip(docs.starts, docs.ends)}
     pairs = [(round(s, 9), warren.translate(d, ends[d])) for d, s in hits]
@@ -124,11 +123,6 @@ def _search_view(warren, query, k=10):
         groups.append((pairs[i][0], frozenset(t for _, t in pairs[i:j])))
         i = j
     return groups
-
-
-def _single_search(warren, query, k):
-    from repro.core import score_bm25
-    return score_bm25(warren, query, k=k)
 
 
 OPS = st.lists(
@@ -196,8 +190,8 @@ def test_search_parity_with_one_replica_killed_per_group(replicated_pair):
                 got = _search_view(sharded, q)
                 assert got == ref, q
                 np.testing.assert_allclose(
-                    [s for _, s in sharded.search(q, k=10)],
-                    [s for _, s in _single_search(single, q, 10)], rtol=1e-9)
+                    [s for _, s in score_bm25(sharded, q, k=10)],
+                    [s for _, s in score_bm25(single, q, k=10)], rtol=1e-9)
     finally:
         for g in range(sharded.n_shards):
             sharded.resurrect(g, g % 2)

@@ -218,19 +218,23 @@ def test_async_scatter_matches_sequential_reads(serving_pair):
     sharded, single = serving_pair
     assert sharded.async_scatter
     with sharded:
-        async_res = {q: sharded.search(q, k=10) for q in QUERIES}
+        async_res = {q: score_bm25(sharded, q, k=10) for q in QUERIES}
         async_docs = len(sharded.annotations(":"))
-        async_stats = sharded.global_stats()
+        async_stats = sharded.map_groups(collection_stats)
         async_gcl = sharded.search_gcl("school", limit=10_000)
     sharded.set_async_scatter(False)
     try:
         with sharded:
             for q in QUERIES:
-                assert sharded.search(q, k=10) == async_res[q]
+                assert score_bm25(sharded, q, k=10) == async_res[q]
             assert len(sharded.annotations(":")) == async_docs
-            seq_stats = sharded.global_stats()
-            assert seq_stats.n_docs == async_stats.n_docs
-            assert seq_stats.avgdl == async_stats.avgdl
+            seq_stats = sharded.map_groups(collection_stats)
+            assert [s.n_docs for s in seq_stats] == \
+                [s.n_docs for s in async_stats]
+            assert [s.avgdl for s in seq_stats] == \
+                [s.avgdl for s in async_stats]
+            for seq, asy in zip(seq_stats, async_stats):
+                np.testing.assert_array_equal(seq.doc_starts, asy.doc_starts)
             assert sharded.search_gcl("school", limit=10_000) == async_gcl
     finally:
         sharded.set_async_scatter(True)
@@ -249,7 +253,8 @@ def test_async_scatter_failover_inside_workers(serving_pair):
     try:
         with sharded, single:
             for q in QUERIES:
-                assert _grouped_hits(sharded, sharded.search(q, k=10)) == \
+                assert _grouped_hits(sharded,
+                                     score_bm25(sharded, q, k=10)) == \
                     _grouped_hits(single, score_bm25(single, q, k=10)), q
     finally:
         for g in range(sharded.n_shards):
@@ -258,13 +263,16 @@ def test_async_scatter_failover_inside_workers(serving_pair):
 
 def test_search_records_timing_breakdown(serving_pair):
     sharded, _ = serving_pair
-    sharded.timings.reset()
-    with sharded:
-        sharded.search(QUERIES[0], k=10)
-    snap = sharded.timings.snapshot()
-    assert snap["queries"] == 1
-    assert snap["scatter_s"] > 0 and snap["score_s"] > 0
-    assert "ms/query" in sharded.timings.summary()
+    server = RetrievalServer(sharded, k=10)
+    try:
+        server.query(QUERIES[0], timeout=60)
+        snap = server.timings.snapshot()
+        assert snap["queries"] == 1
+        assert snap["scatter_s"] > 0 and snap["score_s"] > 0
+        assert snap["merge_s"] > 0
+        assert "ms/query" in server.timings.summary()
+    finally:
+        server.close()
 
 
 # ------------------------------------------------------------------ #
@@ -289,19 +297,30 @@ def test_sharded_search_tie_at_k_boundary():
             index_document(single, text, docid=docid)
             single.commit()
     assert sum(len(g.replicas[0]._segments) > 0 for g in sharded.groups) > 1
+    server = RetrievalServer(sharded, k=10)
+    try:
+        got = server.query("school education", timeout=60)
+    finally:
+        server.close()
     with sharded, single:
-        got = sharded.search("school education", k=10)
-        ref = score_bm25(single, "school education", k=10)
+        ref = score_bm25(sharded, "school education", k=10)
         assert len(got) == len(ref) == 10
-        assert [round(s, 9) for _, s in got] == [round(s, 9) for _, s in ref]
-        # ties truncated at the k boundary may keep different members
-        # (addresses are striped, so tie order differs by design) — every
-        # returned member must belong to the single-index tie class
+        # the served top-k is the reference's, tie members included: at
+        # the k boundary both keep the lowest addresses of the tie class
+        assert [d for d, _ in got] == [d for d, _ in ref]
+        np.testing.assert_allclose([s for _, s in got],
+                                   [s for _, s in ref], rtol=1e-6)
+        single_ref = score_bm25(single, "school education", k=10)
+        assert [round(s, 9) for _, s in ref] == \
+            [round(s, 9) for _, s in single_ref]
+        # addresses are striped, so which tie members sit below the k
+        # boundary differs from the single index by design — every returned
+        # member must belong to the single-index tie class
         ref_all = score_bm25(single, "school education", k=25)
         classes = {}
         for score, texts in _grouped_hits(single, ref_all):
             classes[score] = texts
-        for score, texts in _grouped_hits(sharded, got):
+        for score, texts in _grouped_hits(sharded, ref):
             assert texts <= classes[score], score
 
 
@@ -311,9 +330,10 @@ def test_sharded_search_tie_at_k_boundary():
 @pytest.mark.parametrize("max_postings", [4096, 8])
 def test_sharded_server_matches_single_server(serving_pair, max_postings):
     """The micro-batched scatter/score/merge pipeline returns the same
-    (score, text) ranking as the single-index device path — including with
-    a tight posting cap, where the cap must bind to the GLOBAL top-impact
-    postings, not per-group or doc-order ones."""
+    (score, text) ranking over three groups as over one (a plain warren,
+    served as a single group) — including with a tight posting cap, where
+    the cap must bind to the GLOBAL top-impact postings, not per-group or
+    doc-order ones."""
     sharded, single = serving_pair
     srv_sharded = RetrievalServer(sharded, k=10, max_postings=max_postings)
     srv_single = RetrievalServer(single, k=10, max_postings=max_postings)
@@ -383,5 +403,34 @@ def test_sharded_server_stats_refresh_after_commit(serving_pair):
             sharded.transaction()
             sharded.erase(res[0][0], ends[res[0][0]])
             sharded.commit()
+    finally:
+        server.close()
+
+
+def test_plain_warren_server_sees_commit_without_refresh():
+    """A plain warren is served as one group through the same per-batch
+    fan-out: a commit after construction shows in the next answer, which
+    equals ``score_bm25`` on the new snapshot, with no refresh call."""
+    warren = Warren(DynamicIndex())
+    _ingest(warren, doc_generator(5, 40, mean_len=20))
+    server = RetrievalServer(warren, k=10)
+    try:
+        query = "school education quartz"
+        with warren:
+            before = score_bm25(warren, query, k=10)
+        assert server.query(query, timeout=60)[0][0] == before[0][0]
+        with warren:
+            warren.transaction()
+            index_document(warren, "quartz quartz school education",
+                           docid="fresh")
+            warren.commit()
+        got = server.query(query, timeout=60)
+        with warren:
+            ref = score_bm25(warren, query, k=10)
+            fresh = warren.annotations("docid:fresh")
+        assert got[0][0] == ref[0][0] == int(fresh.starts[0])
+        assert [d for d, _ in got] == [d for d, _ in ref]
+        np.testing.assert_allclose([s for _, s in got],
+                                   [s for _, s in ref], rtol=1e-6)
     finally:
         server.close()

@@ -366,7 +366,7 @@ def test_sharded_demote_query_parity_and_write_promotion(tmp_path):
                 index_document(w, _doc_text(j), docid=f"d{j}")
             w.commit()
     with w:
-        before = w.search("school education student", k=10)
+        before = score_bm25(w, "school education student", k=10)
         d5 = w.annotations("docid:d5")
         span5 = (int(d5.starts[0]), int(d5.ends[0]))
         text5 = w.translate(*span5)
@@ -376,7 +376,7 @@ def test_sharded_demote_query_parity_and_write_promotion(tmp_path):
     assert all(d is not None for d in w.demoted())
 
     with w:                                  # all-cold reads: exact parity
-        assert w.search("school education student", k=10) == before
+        assert score_bm25(w, "school education student", k=10) == before
         assert w.translate(*span5) == text5
         assert len(w.annotations(":")) == 36
         assert w.search_gcl("[docid:d5]")
@@ -390,7 +390,7 @@ def test_sharded_demote_query_parity_and_write_promotion(tmp_path):
     with w:                                  # mixed hot+cold scatter-gather
         assert len(w.annotations(":")) == 37
         assert w.translate(*span5) == text5
-        assert w.search("wind conductor", k=5)
+        assert score_bm25(w, "wind conductor", k=5)
 
     for g in range(3):
         w.promote_group(g)
@@ -448,7 +448,6 @@ def test_retrieval_server_over_tiered_warren(tmp_path):
         host = score_bm25(w, "school education student", k=10)
         full = dict(score_bm25(w, "school education student", k=21))
     server = RetrievalServer(w, k=10)
-    server.refresh_stats()
     got = server.query("school education student", timeout=30)
     server.close()
     # same score profile; doc order may differ only within exact ties
