@@ -457,6 +457,12 @@ class _SnapshotReads:
     def featurize(self, feature: str) -> int:
         return self.featurizer.featurize(feature)
 
+    def max_seqnum(self) -> int:
+        """Largest commit seqnum in the started snapshot (-1 when empty):
+        with the store, it names the snapshot's committed state."""
+        self._require_started()
+        return self._snapshot.max_seqnum()
+
     def annotations(self, feature) -> AnnotationList:
         self._require_started()
         fval = feature if isinstance(feature, int) else self.featurize(feature)
@@ -832,9 +838,6 @@ class StaticWarren(_SnapshotReads):
     @property
     def index(self) -> "StaticWarren":
         return self
-
-    def max_seqnum(self) -> int:
-        return max((r.info.seq_hi for r in self._runs), default=-1)
 
     def clone(self) -> "StaticWarren":
         return StaticWarren(self.directory, self.tokenizer, self.featurizer,

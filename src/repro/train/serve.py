@@ -6,7 +6,8 @@ batch fans out once per shard group (on the warren's scatter pool when
 async scatter is enabled; a warren with no groups is one group), every
 group packs its own compact block with GLOBAL collection statistics read
 in that same fan-out (every posting of the batch in one flat ``[P]``
-array, no padded rows; ``bm25_topk``'s compact form), per-group device
+array, no padded rows; ``bm25_topk``'s compact form; each stem's impacts
+are kept for as long as every group's snapshot key holds), per-group device
 ``bm25_topk`` dispatches overlap the next group's packing, and a global
 k-way merge yields exactly the single-index results.
 
@@ -28,8 +29,10 @@ import dataclasses
 import heapq
 import itertools
 import queue
+import sys
 import threading
 import time
+from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -49,6 +52,8 @@ from repro.dist.parallel import ScatterTimings
 # least two thirds postings once it is past the floor
 POSTINGS_FLOOR = 1024
 COMPILE_THREADS = 8
+# bytes of stem impacts kept for one snapshot signature (_ImpactCache)
+IMPACT_CACHE_BYTES = 256 << 20
 
 
 def posting_buckets(limit: int) -> List[int]:
@@ -179,6 +184,54 @@ class MicroBatcher:
             done._put(failure)
 
 
+class _ImpactCache:
+    """Each stem's per-group ``(doc_idx int32, impact float32)`` arrays, as
+    ``RetrievalServer._global_impacts`` computes them, for ONE snapshot
+    signature: every group's read key, in group order.  A stem's impacts
+    are a function of that snapshot (global df and idf, ``n_docs``, avgdl,
+    document lengths) and the server's posting cap alone, so a batch under
+    the same signature reuses them bit for bit.  A new signature (a commit,
+    erase, merge of groups or rebalance moves some group's key) drops every
+    entry; within one, the least recently used stems go once the entries
+    pass ``limit`` bytes (``IMPACT_CACHE_BYTES``).
+
+    Only the batcher's handler thread touches it (the batch loop is
+    serial), so it takes no lock."""
+
+    def __init__(self):
+        self.limit = IMPACT_CACHE_BYTES
+        self.signature: Optional[tuple] = None
+        # stem -> (per-group arrays or None, bytes)
+        self._entries: "OrderedDict[str, tuple]" = OrderedDict()
+        self.nbytes = 0
+
+    def lookup(self, signature: tuple, stems: List[str]) -> dict:
+        """The cached entries among ``stems`` under ``signature``, marked
+        recently used; a new signature first drops the generation."""
+        if signature != self.signature:
+            self.signature = signature
+            self._entries.clear()
+            self.nbytes = 0
+        found = {}
+        for f in stems:
+            got = self._entries.get(f)
+            if got is not None:
+                self._entries.move_to_end(f)
+                found[f] = got[0]
+        return found
+
+    def insert(self, computed: dict) -> None:
+        for f, per_g in computed.items():
+            # the arrays own their buffers, so getsizeof counts them
+            size = sys.getsizeof(f) + (0 if per_g is None else sum(
+                sys.getsizeof(a) for pair in per_g for a in pair))
+            self._entries[f] = (per_g, size)
+            self.nbytes += size
+        while self.nbytes > self.limit and self._entries:
+            _, (_, size) = self._entries.popitem(last=False)
+            self.nbytes -= size
+
+
 class RetrievalServer:
     """BM25 top-k over an annotative index with batched device scoring.
 
@@ -188,7 +241,10 @@ class RetrievalServer:
     memtable with every on-disk static run, so scoring sees one logical
     hot+cold list per term.  Every batch reads its collection statistics
     from the snapshot it scores, so commits, tier freezes and shard
-    demotions show in the next batch.
+    demotions show in the next batch.  Each stem's impacts are kept for
+    the snapshot they were computed on (``_ImpactCache``), so a batch whose
+    groups all read the same snapshots as the last computes only the stems
+    it is the first to ask for.
 
     A ``ShardedWarren`` is served per group (scatter once per group, score
     per group, merge globally); any other warren is served as one group.
@@ -214,6 +270,7 @@ class RetrievalServer:
         # (group, collection) -> its resident columns; written only by the
         # group's own leg of a fan-out
         self._columns: Dict[Tuple[int, str], aggregate.ResidentColumns] = {}
+        self._impacts = _ImpactCache()
         self.batcher = MicroBatcher(self._handle, batcher)
 
     def timing_summary(self) -> str:
@@ -314,10 +371,44 @@ class RetrievalServer:
             self._note_shapes(qp, p, nb)
 
     # -- text: BM25 per group ---------------------------------------------- #
+    def _cached_impacts(self, signature: tuple, stems: List[str], per: list,
+                        lists: list, n_docs: int) -> dict:
+        """``_global_impacts`` of every stem: the cached ones for this
+        snapshot signature, the rest computed and added to the cache."""
+        term_group = self._impacts.lookup(signature, stems)
+        missed = [si for si, f in enumerate(stems) if f not in term_group]
+        if missed:
+            computed = self._global_impacts(
+                [stems[si] for si in missed], per,
+                [[lst[si] for si in missed] for lst in lists], n_docs)
+            self._impacts.insert(computed)
+            term_group.update(computed)
+        reg = obs.registry()
+        if reg.enabled:
+            miss_postings = sum(len(lst[si]) for lst in lists for si in missed)
+            all_postings = sum(len(t) for lst in lists for t in lst)
+            for result, n, postings in (
+                    ("hit", len(stems) - len(missed),
+                     all_postings - miss_postings),
+                    ("miss", len(missed), miss_postings)):
+                reg.counter("serve_impact_stems_total",
+                            "distinct stems of a batch whose impacts were "
+                            "cached (hit) or computed (miss)",
+                            result=result).inc(n)
+                reg.counter("serve_impact_postings_total",
+                            "postings of the stems counted by "
+                            "serve_impact_stems_total", result=result
+                            ).inc(postings)
+            reg.gauge("serve_impact_cache_bytes",
+                      "bytes of the stem impacts kept for the current "
+                      "snapshot signature").set(self._impacts.nbytes)
+        return term_group
+
     def _global_impacts(self, stems: List[str], per: list, lists: list,
                         n_docs: int) -> dict:
-        """Per stem, every group's (doc_idx, impact) arrays under GLOBAL
-        df/idf and avgdl, or None when no group holds the stem."""
+        """Per stem, every group's (doc_idx int32, impact float32) arrays
+        under GLOBAL df/idf and avgdl, computed in float64 and cast as the
+        packed blocks hold them, or None when no group holds the stem."""
         l, n_groups = self.max_postings, len(per)
         # global stats, computed exactly as collection_stats would over the
         # merged surface (avgdl is order-free; ties merge by address below)
@@ -353,7 +444,8 @@ class RetrievalServer:
                     off += len(di)
                     capped.append((di[m], imp[m]))
                 per_g = capped
-            term_group[f] = per_g
+            term_group[f] = [(di.astype(np.int32), imp.astype(np.float32))
+                             for di, imp in per_g]
         return term_group
 
     def _handle_sharded(self, queries: List[str]
@@ -367,26 +459,29 @@ class RetrievalServer:
         # scatter: ONE fan-out per group for the whole micro-batch — every
         # group returns its stats and its slice of every term list (the
         # fan-out follows the session's pinned routing table, so the group
-        # count comes from the gather, not from the live warren)
-        def read_group(w):
+        # count comes from the gather, not from the live warren), and the
+        # key of the snapshot it read
+        def read_group(w, key):
             with obs.span("scatter.stats"):
                 stats = ranking.collection_stats(w)
-            return stats, [w.annotations(f) for f in stems]
+            return stats, [w.annotations(f) for f in stems], key
 
         t0 = time.perf_counter()
         with self.warren:
-            gathered = self._map_groups(read_group)
+            gathered = self._map_groups(read_group, keyed=True)
         t_scatter = time.perf_counter() - t0
         t0 = time.perf_counter()
         n_groups = len(gathered)
-        per = [s for s, _ in gathered]
-        lists = [lst for _, lst in gathered]
+        per = [s for s, _, _ in gathered]
+        lists = [lst for _, lst, _ in gathered]
         n_docs = sum(s.n_docs for s in per)
         if n_docs == 0:
             self.timings.add(scatter=t_scatter, queries=qn)
             return [[] for _ in queries]
         with obs.phase_timer("bm25_topk", "impacts"):
-            term_group = self._global_impacts(stems, per, lists, n_docs)
+            term_group = self._cached_impacts(
+                tuple(key for _, _, key in gathered), stems, per, lists,
+                n_docs)
 
         qp = max(self._query_slots, self._pad_sizes(qn, 1, 1)[0])
 
